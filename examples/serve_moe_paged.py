@@ -29,88 +29,51 @@ import dataclasses
 import numpy as np
 
 from repro.configs import get_smoke_config
-from repro.models import build_model
-from repro.serving import (PagedEngineConfig, PagedModelRunner,
-                           PagedRealEngine, RealClusterConfig, Request,
-                           RequestState, serve_real_cluster)
+from repro.serving import PagedEngineConfig, PagedModelRunner
+from repro.serving.paged_cluster import (init_params, random_requests,
+                                         report, serve_paged, served_ok)
 
 
-def _requests(cfg, rng, n=12, system=None):
-    reqs = []
-    for i in range(n):
-        toks = rng.integers(0, cfg.vocab_size,
-                            int(rng.integers(8, 40))).tolist()
-        if system is not None:
-            toks = list(system) + toks[:12]
-        reqs.append(Request(
-            req_id=i, prompt_len=len(toks),
-            max_new_tokens=int(rng.integers(4, 10)),
-            arrival_time=0.05 * i, prompt_tokens=toks))
-    return reqs
+def _requests(cfg, rng, system=None):
+    return random_requests(cfg.vocab_size, rng, 12, system=system)
 
 
-def _serve(cfg, params, runner, ecfg, reqs, **cluster_kw):
-    engines = [PagedRealEngine(i, cfg, params, ecfg, runner=runner,
-                               n_sources=2) for i in range(2)]
-    res = serve_real_cluster(
-        reqs, engines, cluster_cfg=RealClusterConfig(window_tokens=300,
-                                                     **cluster_kw))
-    for e in engines:
-        e.pool.check_invariants()
-    return res, engines
+def _serve(runner, ecfg, reqs, **cluster_kw):
+    return serve_paged(reqs, [runner, runner], ecfg, window_tokens=300,
+                       **cluster_kw)
 
 
-def _report(reqs, engines, res):
-    done = [r for r in reqs if r.state is RequestState.FINISHED
-            and not r.error]
-    print(f"served {len(done)}/{len(reqs)} requests on {len(engines)} "
-          f"paged engines ({res.signals['rounds']} cluster rounds)")
-    print(f"dispatch decisions: {res.signals['decisions']}")
-    print(f"prefill dispatches: {res.signals['prefill_dispatches']} "
-          f"(avg {res.signals['prefill_lanes_per_dispatch']:.2f} "
-          f"lanes fused per dispatch)")
-    print(f"preemptions: {res.signals['preemptions']}  "
-          f"stalls: {res.signals['stalled']}  "
-          f"kv peak: {res.signals['kv_peak']:.1%}")
-    print(f"expert migrations: {res.signals['migrations']} "
-          f"({res.signals['expert_moves']} expert moves)")
-    print(f"requests per engine: {res.signals['per_engine']}")
-    print(f"mean ttft {res.mean_ttft:.2f}s  mean e2e {res.mean_e2e:.2f}s "
-          f"(virtual time)")
-
-
-def _chaos(cfg, params, runner, ecfg):
+def _chaos(cfg, runner, ecfg):
     """Crash engine 1 mid-run, recover it, prove nothing was lost."""
     from repro.ft import FaultEvent, FaultPlan
     from repro.ft.health import HealthConfig
 
     mk = lambda: _requests(cfg, np.random.default_rng(0))
-    res0, _ = _serve(cfg, params, runner, ecfg, base := mk())
+    _serve(runner, ecfg, base := mk())
     want = {r.req_id: r.output_tokens for r in base}
 
     plan = FaultPlan(events=(FaultEvent("crash", 1, 10),
                              FaultEvent("recover", 1, 22)))
     res, engines = _serve(
-        cfg, params, runner, ecfg, reqs := mk(), fault_plan=plan,
+        runner, ecfg, reqs := mk(), fault_plan=plan,
         health_cfg=HealthConfig(trace_timeout_s=0.3))
 
     print("== chaos: engine 1 crashes at round 10, recovers at 22 ==")
-    _report(reqs, engines, res)
+    report(reqs, engines, res)
     print(f"health events: {res.signals['health_events']}")
     print(f"engine failures: {res.signals['n_failures']}  "
           f"requests recovered: {res.signals['recovered_requests']}  "
           f"recompute tokens: {res.signals['recovery_recompute_tokens']}")
     exact = all(r.full_output_tokens == want[r.req_id] for r in reqs)
-    lost = [r.req_id for r in reqs
-            if r.state is not RequestState.FINISHED or r.error]
+    ok = {r.req_id for r in served_ok(reqs)}
+    lost = [r.req_id for r in reqs if r.req_id not in ok]
     print(f"bit-exact vs fault-free: {exact}  lost/errored: {lost}")
     assert exact and not lost
 
 
 def main(shared_prefix: bool = False, chaos: bool = False):
-    import jax
     cfg = get_smoke_config("qwen3-moe-30b-a3b")
-    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    params = init_params(cfg, seed=0)
 
     ecfg = PagedEngineConfig(page_size=8, n_pages=32, max_blocks_per_req=8,
                              max_batch=4, token_budget=16,
@@ -118,26 +81,25 @@ def main(shared_prefix: bool = False, chaos: bool = False):
     runner = PagedModelRunner(cfg, params, ecfg, n_sources=2)
 
     if chaos:
-        _chaos(cfg, params, runner, ecfg)
+        _chaos(cfg, runner, ecfg)
         return
     if not shared_prefix:
         reqs = _requests(cfg, np.random.default_rng(0))
-        res, engines = _serve(cfg, params, runner, ecfg, reqs)
-        _report(reqs, engines, res)
+        res, engines = _serve(runner, ecfg, reqs)
+        report(reqs, engines, res)
         return
 
     # shared-system-prompt workload, sharing on vs off on the same stream
     system = np.random.default_rng(7).integers(0, cfg.vocab_size, 24)
     mk = lambda: _requests(cfg, np.random.default_rng(0), system=system)
-    res_off, eng_off = _serve(cfg, params, runner, ecfg, reqs_off := mk())
+    res_off, eng_off = _serve(runner, ecfg, reqs_off := mk())
     shared_cfg = dataclasses.replace(ecfg, prefix_sharing=True)
-    res_on, eng_on = _serve(cfg, params, runner, shared_cfg,
-                            reqs_on := mk())
+    res_on, eng_on = _serve(runner, shared_cfg, reqs_on := mk())
 
     print("== sharing OFF ==")
-    _report(reqs_off, eng_off, res_off)
+    report(reqs_off, eng_off, res_off)
     print("== sharing ON (ref-counted prefix cache + COW) ==")
-    _report(reqs_on, eng_on, res_on)
+    report(reqs_on, eng_on, res_on)
     identical = all(a.output_tokens == b.output_tokens
                     for a, b in zip(reqs_off, reqs_on))
     saved = res_off.signals["pages_allocated"] \

@@ -26,6 +26,23 @@ def config() -> ModelConfig:
     )
 
 
+def one_chip_config() -> ModelConfig:
+    """Published widths, 4 of the 48 layers, for one 16 GB TPU v5e chip.
+
+    Every width is Qwen3-30B-A3B's own (d_model 2048, 32 query / 4 KV heads
+    of 128, 128 experts of d_expert 768, top-8, vocab 151,936, qk_norm,
+    rope theta 1e6, bf16); weights are random from a seed. Only depth is
+    cut: 4 layers are 6.2 GB of weights (each layer's 128 experts are
+    1.2 GB, the embedding and the untied head 0.6 GB each). An expert
+    migration (``models/transformer.migrate_params_for_placement``)
+    briefly holds a second copy of the stacked expert weights, about
+    4.8 GB, and each paged step writes a fresh copy of the page pool, so
+    4 layers plus a pool of ~0.5 GB still fit 16 GB; 6 layers would not
+    survive a migration.
+    """
+    return reduced(config(), n_layers=4)
+
+
 def smoke_config() -> ModelConfig:
     return reduced(
         config(),

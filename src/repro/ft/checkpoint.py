@@ -23,33 +23,10 @@ import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
+import zstandard
 
-_ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
-
-try:
-    import zstandard
-    _CTX = zstandard.ZstdCompressor(level=3)
-    _DCTX = zstandard.ZstdDecompressor()
-    _compress = _CTX.compress
-except ImportError:  # minimal installs: stdlib zlib
-    import zlib
-    zstandard = None
-
-    def _compress(data):
-        return zlib.compress(data, 3)
-
-
-def _decompress(data):
-    """Sniff the frame magic so checkpoints stay portable between installs
-    with and without zstandard (leaf files always carry the .zst suffix)."""
-    if data[:4] == _ZSTD_MAGIC:
-        if zstandard is None:
-            raise RuntimeError(
-                "checkpoint leaf is zstd-compressed but zstandard is not "
-                "installed; pip install zstandard to restore it")
-        return _DCTX.decompress(data)
-    import zlib
-    return zlib.decompress(data)
+_CTX = zstandard.ZstdCompressor(level=3)
+_DCTX = zstandard.ZstdDecompressor()
 
 
 def _flatten(tree):
@@ -76,7 +53,7 @@ def save_checkpoint(path: str, tree: Any, *, step: int = 0,
         arr = np.asarray(leaf)
         manifest["leaves"].append(
             {"shape": list(arr.shape), "dtype": str(arr.dtype)})
-        payload = _compress(arr.tobytes())
+        payload = _CTX.compress(arr.tobytes())
         with open(os.path.join(tmp, f"leaf_{i:05d}.zst"), "wb") as f:
             f.write(payload)
     with open(os.path.join(tmp, "manifest.json"), "w") as f:
@@ -106,7 +83,7 @@ def restore_checkpoint(path: str, like: Any, *, shardings: Any = None) -> Any:
     for i, (meta, ref, shd) in enumerate(
             zip(manifest["leaves"], like_leaves, shard_leaves)):
         with open(os.path.join(path, f"leaf_{i:05d}.zst"), "rb") as f:
-            raw = _decompress(f.read())
+            raw = _DCTX.decompress(f.read())
         arr = np.frombuffer(raw, dtype=np.dtype(meta["dtype"])).reshape(
             meta["shape"])
         if tuple(arr.shape) != tuple(np.shape(ref)):

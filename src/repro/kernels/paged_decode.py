@@ -32,9 +32,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(bt_ref, q_ref, k_ref, v_ref, ctx_ref, *rest,
+def _kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *rest,
             scale, page_size, n_blocks, quant):
-    # args after ctx_ref: [k_scale_ref, v_scale_ref (quant only)], o_ref,
+    # args after v_ref: [k_scale_ref, v_scale_ref (quant only)], o_ref,
     # then the three scratch buffers
     if quant:
         ks_ref, vs_ref, o_ref = rest[0], rest[1], rest[2]
@@ -55,7 +55,7 @@ def _kernel(bt_ref, q_ref, k_ref, v_ref, ctx_ref, *rest,
     if quant:                                     # int8 pages: dequant on read
         k = k * ks_ref[0][..., None]              # scales (ps, Hkv)
         v = v * vs_ref[0][..., None]
-    ctx = ctx_ref[0, 0]                           # scalar: live tokens
+    ctx = ctx_ref[pl.program_id(0)]               # scalar: live tokens
 
     Hq, hd = q.shape
     ps, Hkv, _ = k.shape
@@ -104,26 +104,30 @@ def paged_decode_pallas(q, k_pages, v_pages, block_tables, ctx_lens, *,
 
     kernel = functools.partial(_kernel, scale=scale, page_size=ps,
                                n_blocks=NB, quant=quant)
+    # index maps see (b, j, block table, ctx_lens): both ride in SMEM via
+    # scalar prefetch (a (1, 1) VMEM block of ctx_lens would break the TPU
+    # tiling rule on the last two block dims)
     in_specs = [
-        pl.BlockSpec((1, Hq, hd), lambda b, j, bt: (b, 0, 0)),
+        pl.BlockSpec((1, Hq, hd), lambda b, j, bt, cl: (b, 0, 0)),
         pl.BlockSpec((1, ps, Hkv, hd),
-                     lambda b, j, bt: (bt[b, j], 0, 0, 0)),
+                     lambda b, j, bt, cl: (bt[b, j], 0, 0, 0)),
         pl.BlockSpec((1, ps, Hkv, hd),
-                     lambda b, j, bt: (bt[b, j], 0, 0, 0)),
-        pl.BlockSpec((1, 1), lambda b, j, bt: (b, 0)),
+                     lambda b, j, bt, cl: (bt[b, j], 0, 0, 0)),
     ]
-    args = [bt, q, k_pages, v_pages, ctx_lens[:, None].astype(jnp.int32)]
+    args = [bt, ctx_lens.astype(jnp.int32), q, k_pages, v_pages]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, ps, Hkv), lambda b, j, bt: (bt[b, j], 0, 0)),
-            pl.BlockSpec((1, ps, Hkv), lambda b, j, bt: (bt[b, j], 0, 0)),
+            pl.BlockSpec((1, ps, Hkv),
+                         lambda b, j, bt, cl: (bt[b, j], 0, 0)),
+            pl.BlockSpec((1, ps, Hkv),
+                         lambda b, j, bt, cl: (bt[b, j], 0, 0)),
         ]
         args += [k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                    # the block table
+        num_scalar_prefetch=2,                    # block table, ctx_lens
         grid=(B, NB),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, hd), lambda b, j, bt: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, hd), lambda b, j, bt, cl: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, G), jnp.float32),        # running max m
             pltpu.VMEM((Hkv, G), jnp.float32),        # running sum l

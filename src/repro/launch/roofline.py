@@ -115,8 +115,6 @@ def model_flops_for(cfg: ModelConfig, shape: ShapeConfig) -> float:
 def _analyze(lowered):
     compiled = lowered.compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):       # older jax: one dict per device program
-        ca = ca[0] if ca else {}
     coll = collective_bytes_from_text(compiled.as_text())
     return (float(ca.get("flops", 0.0)),
             float(ca.get("bytes accessed", 0.0)),

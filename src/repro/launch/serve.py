@@ -71,8 +71,11 @@ def main():
     if args.real:
         import os
         import sys
+
+        from repro.launch.compile_cache import use_compile_cache
         root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__)))))
+        use_compile_cache(root)
         sys.path.insert(0, root)   # examples/ lives at the repo root
         if args.paged:
             from examples.serve_moe_paged import main as real_main

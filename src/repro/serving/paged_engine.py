@@ -94,11 +94,17 @@ class PagedModelRunner:
     """Jitted paged-model entry points, shared across a cluster's engines."""
 
     def __init__(self, cfg, params, ecfg: PagedEngineConfig, *,
-                 n_sources: int, ragged_dispatch: Optional[bool] = None):
+                 n_sources: int, ragged_dispatch: Optional[bool] = None,
+                 device: Optional[jax.Device] = None):
+        """``device`` commits the params and every page pool this runner
+        makes to one device, so the jitted steps run there (one DP replica
+        per chip); None leaves placement to JAX's default device."""
         if cfg.input_mode != "tokens":
             raise NotImplementedError("paged runtime serves token models")
         self.cfg = cfg
-        self.params = params
+        self.device = device
+        self.params = params if device is None \
+            else jax.device_put(params, device)
         self.ecfg = ecfg
         self.n_sources = n_sources
         self.ragged_dispatch = (moe_mod.PERF["ragged_dispatch"]
@@ -160,10 +166,13 @@ class PagedModelRunner:
                       source_ids):
         B, S = (int(batch["tokens"].shape[0]), int(batch["tokens"].shape[1]))
         self._count_padding(B * S, int(np.asarray(batch["chunk_lens"]).sum()))
+        return self._prefill_jit(B, S)(self.params, batch, pages,
+                                       block_tables, placement, source_ids)
+
+    def _prefill_jit(self, B: int, S: int):
         if (B, S) not in self._prefill_jits:  # one compile per (lane, chunk)
             self._prefill_jits[(B, S)] = jax.jit(self._pin(self._prefill_fn))
-        return self._prefill_jits[(B, S)](self.params, batch, pages,
-                                          block_tables, placement, source_ids)
+        return self._prefill_jits[(B, S)]
 
     def mixed_step(self, batch, pages, block_tables, placement, source_ids):
         """One fused mixed-group dispatch (decode + prefill lanes)."""
@@ -198,9 +207,51 @@ class PagedModelRunner:
             f"{self.ecfg.lane_buckets[-1]}")
 
     def init_pages(self):
+        if self.device is None:
+            return self._init_pages()
+        with jax.default_device(self.device):
+            return jax.device_put(self._init_pages(), self.device)
+
+    def _init_pages(self):
         return tfm.init_paged_cache(self.cfg, self.ecfg.n_pages + 1,
                                     self.ecfg.page_size,
                                     kv_dtype=self.ecfg.kv_dtype)
+
+    def _inert_lanes(self, B: int):
+        """Zeroed (B,) lane values and (B, NB) block tables: every row
+        points at the garbage page, so the lanes write there and attend
+        to nothing."""
+        return (jnp.zeros((B,), jnp.int32),
+                jnp.zeros((B, self.ecfg.max_blocks_per_req), jnp.int32))
+
+    def _inert_decode_args(self, pages):
+        z, bt = self._inert_lanes(self.ecfg.max_batch)
+        return (z, pages, z, bt, z.astype(bool),
+                jnp.asarray(tfm.identity_placement(self.cfg)), z)
+
+    def lower_decode(self):
+        """The jitted decode step lowered at this runner's shapes (for
+        inspecting what the compiler emits, e.g. its custom calls)."""
+        pages = jax.eval_shape(self._init_pages)
+        return self._decode_jit.lower(self.params,
+                                      *self._inert_decode_args(pages))
+
+    def warmup(self) -> None:
+        """Compile the decode step and every (lane, chunk) prefill bucket by
+        running each once on inert lanes of a scratch pool, so serving
+        starts with no compiles. Mixed-step shapes still compile on first
+        use."""
+        pages = self.init_pages()
+        args = self._inert_decode_args(pages)
+        jax.block_until_ready(self._decode_jit(self.params, *args))
+        placement = args[5]
+        for B in self.ecfg.lane_buckets:
+            z, bt = self._inert_lanes(B)
+            for S in self.ecfg.chunk_buckets:
+                batch = {"tokens": jnp.zeros((B, S), jnp.int32),
+                         "chunk_starts": z, "chunk_lens": z}
+                jax.block_until_ready(self._prefill_jit(B, S)(
+                    self.params, batch, pages, bt, placement, z))
 
 
 class PagedRealEngine:

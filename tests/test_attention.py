@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal installs: deterministic fallback shim
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.attention import flash_attention, ring_positions
 from repro.models.ssm import (_mlstm_chunk, init_mamba, init_mlstm,

@@ -24,10 +24,7 @@ Layers:
 """
 import dataclasses
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 import numpy as np

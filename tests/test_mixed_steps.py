@@ -3,10 +3,10 @@
 Four layers, mirroring test_step_planner.py's proof structure:
 
 * **model oracle** — ``mixed_step_paged`` (decode rows + prefill rows in
-  one (B, S) dispatch) is bit-exact against the split
-  ``decode_step_paged`` + ``prefill_chunk_paged`` calls it replaces:
-  per-lane logits, every owned page, and the mask-reduced MoE statistic
-  sums, across decode+prefill and all-decode rounds;
+  one (B, S) dispatch) agrees with the split ``decode_step_paged`` +
+  ``prefill_chunk_paged`` calls it replaces: per-lane logits within a
+  written tolerance, every owned page and the mask-reduced MoE statistic
+  sums exactly, across decode+prefill and all-decode rounds;
 * **engine differential** — ``PagedRealEngine`` with ``mixed_steps`` on
   vs off serves identical streams to token-identical outputs, finish
   times and MoE window statistics with strictly fewer total model
@@ -40,8 +40,9 @@ from repro.serving.step_plan import written_kv_len
 def test_mixed_step_model_oracle_bit_exact(tiny_model, shared_runner):
     """Two rounds of interleaved serving — (2 decode + 1 prefill) fused,
     then an all-decode fused step — against the split dispatches, on
-    independently threaded page trees: lane logits, owned pages and the
-    MoE statistic sums must all match bit for bit. (aux_loss is the one
+    independently threaded page trees: lane logits within
+    ``tsp.LOGITS_TOL``, owned pages and the MoE statistic sums bit for
+    bit. (aux_loss is the one
     deliberate exception: it normalizes over padded shapes, which differ
     between the fused and split dispatches, and nothing in serving
     consumes it.)"""
@@ -127,9 +128,11 @@ def test_mixed_step_model_oracle_bit_exact(tiny_model, shared_runner):
     ld, pa, sd = decode(pa, [(0, t0, 11), (2, t2, 9)])
     lp, pa, sp = prefill(pa, 1, 6, 7)
     lm, pb, sm = mixed(pb, [(0, t0, 11), (2, t2, 9)], [(1, 6, 7)])
-    np.testing.assert_array_equal(np.asarray(ld[0]), np.asarray(lm[0]))
-    np.testing.assert_array_equal(np.asarray(ld[1]), np.asarray(lm[1]))
-    np.testing.assert_array_equal(np.asarray(lp[0]), np.asarray(lm[2]))
+    close = lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), **tsp.LOGITS_TOL)
+    close(ld[0], lm[0])
+    close(ld[1], lm[1])
+    close(lp[0], lm[2])
     A, B = stat_sums([sd, sp]), stat_sums([sm])
     for k in A:
         np.testing.assert_array_equal(A[k], B[k])
@@ -142,8 +145,7 @@ def test_mixed_step_model_oracle_bit_exact(tiny_model, shared_runner):
     lm2, pb, sm2 = mixed(pb, items, [])
     assert int(lm2.shape[0]) == 4 and int(np.asarray(lm2).ndim) == 2
     for j in range(3):
-        np.testing.assert_array_equal(np.asarray(ld2[j]),
-                                      np.asarray(lm2[j]))
+        close(ld2[j], lm2[j])
     A, B = stat_sums([sd2]), stat_sums([sm2])
     for k in A:
         np.testing.assert_array_equal(A[k], B[k])

@@ -6,10 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal installs: deterministic fallback shim
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_smoke_config
 from repro.models import moe as moe_mod

@@ -321,6 +321,26 @@ def test_dpengine_stall_surfaces_in_trace():
     assert held + 60 <= e.pool.total_blocks and e.pool.free_blocks >= 0
 
 
+def test_runner_on_a_device_warms_every_step_shape(tiny_model):
+    """A runner given a device commits its params and every pool it makes
+    there; warmup compiles the decode step and each (lane, chunk) prefill
+    bucket without counting as served traffic."""
+    cfg, params = tiny_model
+    dev = jax.devices()[-1]
+    ecfg = PagedEngineConfig(page_size=8, n_pages=8, max_blocks_per_req=4,
+                             max_batch=2, token_budget=16, chunk_buckets=(8,),
+                             max_prefill_lanes=2, lane_buckets=(1, 2),
+                             attn_backend="xla")
+    runner = PagedModelRunner(cfg, params, ecfg, n_sources=2, device=dev)
+    for x in jax.tree.leaves((runner.params, runner.init_pages())):
+        assert x.committed and x.devices() == {dev}
+    runner.warmup()
+    assert sorted(runner._prefill_jits) == [(1, 8), (2, 8)]
+    assert runner.padded_tokens_total == 0
+    bt = f"tensor<{ecfg.max_batch}x{ecfg.max_blocks_per_req}xi32>"
+    assert bt in runner.lower_decode().as_text()   # the served decode shape
+
+
 # ------------------------------------------------------------ cluster e2e
 @pytest.mark.slow
 def test_live_expert_migration_moves_weights(tiny_model):

@@ -15,10 +15,7 @@ Three layers of proof for ``SharedPagedAllocator`` and its engine wiring:
 import dataclasses
 from collections import OrderedDict
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 import numpy as np

@@ -1,10 +1,7 @@
 """Gimbal core unit + property tests (Algorithm 1 & 2, placement, MINLP)."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal installs: deterministic fallback shim
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (BaselineScheduler, EngineTrace, GimbalScheduler,
                         PlacementConfig, QueueConfig, SchedulerConfig,

@@ -1,10 +1,7 @@
 """Serving substrate: KV pool, engine continuous batching, end-to-end sim."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal installs: deterministic fallback shim
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.serving import (BlockPool, DPEngine, EngineConfig, PAPER_SYSTEMS,
                            Request, RequestState, simulate)

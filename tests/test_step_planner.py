@@ -2,9 +2,9 @@
 
 Four layers:
 
-* **model level** — one fused B>1 ``prefill_chunk_paged`` dispatch is
-  bit-exact, lane for lane, against the B=1 sequential calls (logits,
-  written pages, and masked MoE statistics);
+* **model level** — one fused B>1 ``prefill_chunk_paged`` dispatch
+  agrees, lane for lane, with the B=1 sequential calls (logits within a
+  written tolerance; written pages and masked MoE statistics exactly);
 * **engine differential** — ``PagedRealEngine`` with lane fusion on
   (``max_prefill_lanes=8``) vs off (=1) serves identical streams to
   token-identical outputs and finish order with strictly fewer prefill
@@ -21,10 +21,7 @@ Four layers:
 """
 import dataclasses
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 import numpy as np
@@ -41,6 +38,14 @@ from repro.core.queue_policy import QueueConfig, order_queue
 
 
 # ================================================================ helpers
+# Fused and split dispatches may reduce in a different order: XLA promises
+# no batch-shape-invariant reductions on the CPU or the chip (observed gap
+# 2.4e-7 on the CPU). The model computes in bf16, so a reordered f32 sum
+# can also flip one bf16 rounding (2^-8 relative) upstream of the logits;
+# a lane that read the wrong pages or positions is off by O(1).
+LOGITS_TOL = dict(rtol=1e-2, atol=1e-2)
+
+
 def _mk_requests(cfg, n, prompt_lens, max_new=4, seed=0, gap=0.001):
     rng = np.random.default_rng(seed)
     return [Request(
@@ -65,8 +70,9 @@ def _drive(engine, reqs, max_steps=400):
 
 # ================================================================ model level
 def test_model_level_batched_prefill_bit_exact(tiny_model, shared_runner):
-    """One fused B-lane dispatch == the B=1 calls, token for token: lane
-    logits, every written page, and the mask-reduced MoE statistics."""
+    """One fused B-lane dispatch == the B=1 calls: lane logits within
+    ``LOGITS_TOL``, every written page and the mask-reduced MoE
+    statistics exactly."""
     cfg, params = tiny_model
     runner = shared_runner
     ps = runner.ecfg.page_size
@@ -129,8 +135,8 @@ def test_model_level_batched_prefill_bit_exact(tiny_model, shared_runner):
     lg_b, pages_b, stats_b = run(batched=True)
     lg_s, pages_s, stats_s = run(batched=False)
     for i in range(len(prompts)):
-        np.testing.assert_array_equal(lg_b[i], lg_s[i],
-                                      err_msg=f"lane {i} logits diverged")
+        np.testing.assert_allclose(lg_b[i], lg_s[i], **LOGITS_TOL,
+                                   err_msg=f"lane {i} logits diverged")
     for pos in pages_b:
         for arr in ("k", "v"):
             np.testing.assert_array_equal(
