@@ -139,6 +139,7 @@ def paged_decode_pallas(q, k_pages, v_pages, block_tables, ctx_lens, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode",        # the custom call's name in traces
     )(*args)
 
 

@@ -15,10 +15,12 @@ from repro.models import moe as moe_mod
 from repro.serving.request import Request
 
 
-def pin_dispatch_mode(fn, get_mode):
+def pin_dispatch_mode(fn, get_mode, name: Optional[str] = None):
     """Wrap ``fn`` so the PERF['ragged_dispatch'] toggle equals
     ``get_mode()`` while jit traces it — per-engine A/B dispatch modes must
-    not leak into other engines' compiles."""
+    not leak into other engines' compiles. The wrapper is called ``name``
+    (``fn``'s own name by default), which ``jax.jit`` gives the program:
+    ``jit_<name>`` in HLO dumps and device traces."""
     def traced(*args, **kw):
         prev = moe_mod.PERF["ragged_dispatch"]
         moe_mod.PERF["ragged_dispatch"] = get_mode()
@@ -26,6 +28,7 @@ def pin_dispatch_mode(fn, get_mode):
             return fn(*args, **kw)
         finally:
             moe_mod.PERF["ragged_dispatch"] = prev
+    traced.__name__ = traced.__qualname__ = name or fn.__name__
     return traced
 
 

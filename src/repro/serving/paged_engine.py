@@ -29,6 +29,7 @@ from repro.core.queue_policy import QueueConfig, order_queue
 from repro.core.traces import EngineTrace
 from repro.models import moe as moe_mod
 from repro.models import transformer as tfm
+from repro.serving import spans
 from repro.serving.costmodel import SwapCostModel
 from repro.serving.engine_util import (PrefixSummaryShipper,
                                        drain_window_stats, pin_dispatch_mode,
@@ -36,6 +37,7 @@ from repro.serving.engine_util import (PrefixSummaryShipper,
 from repro.serving.kv_tier import HostKVTier, TieredSharedAllocator
 from repro.serving.paged import PagedBlockAllocator, SharedPagedAllocator
 from repro.serving.request import Request, RequestState
+from repro.serving.spans import span
 from repro.serving.step_plan import (PlannerConfig, PrefillLane, StepPlan,
                                      StepPlanner, mixed_chunk_bucket,
                                      written_kv_len)
@@ -112,16 +114,17 @@ class PagedModelRunner:
                                 else ragged_dispatch)
         self._prefill_jits: Dict[Tuple[int, int], object] = {}
         self._mixed_jits: Dict[Tuple[int, int], object] = {}
-        self._decode_jit = jax.jit(self._pin(self._decode_fn))
+        self._decode_jit = jax.jit(self._pin(self._decode_fn, "decode_step"))
         # (B, S)-bucket padding accounting across every dispatch this
         # runner serves: padded minus real tokens. The cost-aware mixed
         # grouper exists to push waste down; the bench reads these.
         self.padding_waste_tokens = 0
         self.padded_tokens_total = 0
 
-    def _pin(self, fn):
-        """Pin this runner's MoE dispatch mode while jit traces ``fn``."""
-        return pin_dispatch_mode(fn, lambda: self.ragged_dispatch)
+    def _pin(self, fn, name: str):
+        """Pin this runner's MoE dispatch mode while jit traces ``fn``; the
+        program is called ``jit_<name>``."""
+        return pin_dispatch_mode(fn, lambda: self.ragged_dispatch, name)
 
     def _decode_fn(self, params, tokens, pages, lengths, block_tables,
                    active, placement, source_ids):
@@ -171,7 +174,8 @@ class PagedModelRunner:
 
     def _prefill_jit(self, B: int, S: int):
         if (B, S) not in self._prefill_jits:  # one compile per (lane, chunk)
-            self._prefill_jits[(B, S)] = jax.jit(self._pin(self._prefill_fn))
+            self._prefill_jits[(B, S)] = jax.jit(
+                self._pin(self._prefill_fn, "prefill_step"))
         return self._prefill_jits[(B, S)]
 
     def mixed_step(self, batch, pages, block_tables, placement, source_ids):
@@ -179,7 +183,8 @@ class PagedModelRunner:
         B, S = (int(batch["tokens"].shape[0]), int(batch["tokens"].shape[1]))
         self._count_padding(B * S, int(np.asarray(batch["chunk_lens"]).sum()))
         if (B, S) not in self._mixed_jits:
-            self._mixed_jits[(B, S)] = jax.jit(self._pin(self._mixed_fn))
+            self._mixed_jits[(B, S)] = jax.jit(
+                self._pin(self._mixed_fn, "mixed_step"))
         return self._mixed_jits[(B, S)](self.params, batch, pages,
                                         block_tables, placement, source_ids)
 
@@ -555,28 +560,33 @@ class PagedRealEngine:
         declarative plan on the data plane."""
         if self.dead:
             return []
-        if self.sharing and self.ecfg.register_ttl_s > 0:
-            self.pool.expire_registrations(now)
-        plan = self.planner.plan(now)
-        self.prefix_hit_tokens += plan.prefix_hit_tokens
-        self._stalled_last = plan.n_stalled
-        self.n_stalled_total += plan.n_stalled
-        self._swap_in_blocked_last = plan.swap_in_blocked
-        self.swap_in_blocked_total += plan.swap_in_blocked
-        self._swap_in_bytes_window += sum(rec.nbytes
-                                          for rec in plan.swap_in)
+        with span(spans.ENGINE_STEP, engine=self.engine_id):
+            if self.sharing and self.ecfg.register_ttl_s > 0:
+                self.pool.expire_registrations(now)
+            with span(spans.PLAN) as s:
+                plan = self.planner.plan(now)
+                s.set_metadata(decode=len(plan.decode),
+                               prefill_groups=len(plan.prefill_groups),
+                               mixed_groups=len(plan.mixed_groups))
+            self.prefix_hit_tokens += plan.prefix_hit_tokens
+            self._stalled_last = plan.n_stalled
+            self.n_stalled_total += plan.n_stalled
+            self._swap_in_blocked_last = plan.swap_in_blocked
+            self.swap_in_blocked_total += plan.swap_in_blocked
+            self._swap_in_bytes_window += sum(rec.nbytes
+                                              for rec in plan.swap_in)
 
-        finished: List[Request] = []
-        if plan.mixed_groups:
-            finished.extend(self._run_mixed(plan, now))
-        else:
-            for group in plan.prefill_groups:
-                finished.extend(self._run_prefill_group(group, now))
-            if plan.decode:
-                finished.extend(self._run_decode(plan.decode, now))
-        if plan.has_work:
-            self.step_count += 1
-        return finished
+            finished: List[Request] = []
+            if plan.mixed_groups:
+                finished.extend(self._run_mixed(plan, now))
+            else:
+                for group in plan.prefill_groups:
+                    finished.extend(self._run_prefill_group(group, now))
+                if plan.decode:
+                    finished.extend(self._run_decode(plan.decode, now))
+            if plan.has_work:
+                self.step_count += 1
+            return finished
 
     # ---- data-plane calls ------------------------------------------------
     def _run_prefill_group(self, group: List[PrefillLane],
@@ -585,56 +595,71 @@ class PagedRealEngine:
         the runner's (B, S) bucket; padding lanes get all-garbage block
         tables and zero chunk_lens, so their rows write to page 0, attend
         to nothing and are masked out of the MoE statistics."""
-        S = self.runner.bucket_for(max(l.chunk for l in group))
-        B = self.runner.lane_bucket_for(len(group))
-        toks = np.zeros((B, S), np.int32)
-        starts = np.zeros(B, np.int32)
-        lens = np.zeros(B, np.int32)
-        rids: List[Optional[int]] = [None] * B
-        for i, l in enumerate(group):
-            toks[i, :l.chunk] = l.req.prompt_tokens[l.start:l.start + l.chunk]
-            starts[i] = l.start
-            lens[i] = l.chunk
-            rids[i] = l.req.req_id
-        batch = {"tokens": jnp.asarray(toks),
-                 "chunk_starts": jnp.asarray(starts),
-                 "chunk_lens": jnp.asarray(lens)}
-        bt = jnp.asarray(self.pool.block_table_array(
-            rids, self.ecfg.max_blocks_per_req))
+        with span(spans.INPUTS, kind="prefill"):
+            S = self.runner.bucket_for(max(l.chunk for l in group))
+            B = self.runner.lane_bucket_for(len(group))
+            toks = np.zeros((B, S), np.int32)
+            starts = np.zeros(B, np.int32)
+            lens = np.zeros(B, np.int32)
+            rids: List[Optional[int]] = [None] * B
+            for i, l in enumerate(group):
+                toks[i, :l.chunk] = \
+                    l.req.prompt_tokens[l.start:l.start + l.chunk]
+                starts[i] = l.start
+                lens[i] = l.chunk
+                rids[i] = l.req.req_id
+            batch = {"tokens": jnp.asarray(toks),
+                     "chunk_starts": jnp.asarray(starts),
+                     "chunk_lens": jnp.asarray(lens)}
+            bt = jnp.asarray(self.pool.block_table_array(
+                rids, self.ecfg.max_blocks_per_req))
+            placement = jnp.asarray(self.placement)
+            source_ids = jnp.full((B,), self.engine_id, jnp.int32)
+        n_tokens = sum(l.chunk for l in group)
         t0 = time.perf_counter()
-        logits, self.pages, stats = self.runner.prefill_chunk(
-            batch, self.pages, bt, jnp.asarray(self.placement),
-            jnp.full((B,), self.engine_id, jnp.int32))
-        if self.swap_cost is not None:
-            jax.block_until_ready(logits)
-            self.swap_cost.observe_prefill(sum(l.chunk for l in group),
-                                           time.perf_counter() - t0)
+        with span(spans.LAUNCH + "prefill", lanes=len(group),
+                  tokens=n_tokens, engine=self.engine_id):
+            logits, self.pages, stats = self.runner.prefill_chunk(
+                batch, self.pages, bt, placement, source_ids)
+        with span(spans.READBACK, kind="prefill"):
+            if self.swap_cost is not None:
+                jax.block_until_ready(logits)
+                self.swap_cost.observe_prefill(n_tokens,
+                                               time.perf_counter() - t0)
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))   # sync point
         self.prefill_dispatches += 1
         self.prefill_lanes_total += len(group)
         if stats is not None:
-            self.stats_log.append(jax.tree.map(np.asarray, stats))
+            with span(spans.STATS_READBACK):
+                self.stats_log.append(jax.tree.map(np.asarray, stats))
         finished = []
-        for i, l in enumerate(group):
-            r = l.req
-            r.prefill_done += l.chunk
-            self.total_prefill_tokens += l.chunk
-            if self.sharing:
-                # full pages just completed become shareable (first writer
-                # wins). Mid-life registration is floored to the page
-                # boundary: indexing the in-progress partial page would
-                # force a COW on the very next chunk/decode write into it —
-                # the token-granular tail is registered once at finish.
-                full = r.prefill_done - r.prefill_done % self.ecfg.page_size
-                self.pool.register_prefix(r.req_id, r.prompt_tokens[:full])
-            if r.remaining_prefill == 0:
-                tok = int(jnp.argmax(logits[i]))
-                r.output_tokens = [tok]
-                r.generated = 1
-                if r.first_token_time < 0:   # a resumed request's client
-                    r.first_token_time = now  # saw its first token pre-crash
-                if r.done:
-                    self._finish(r, now)
-                    finished.append(r)
+        with span(spans.COMMIT) as s:
+            for i, l in enumerate(group):
+                r = l.req
+                r.prefill_done += l.chunk
+                self.total_prefill_tokens += l.chunk
+                if self.sharing:
+                    # full pages just completed become shareable (first
+                    # writer wins). Mid-life registration is floored to the
+                    # page boundary: indexing the in-progress partial page
+                    # would force a COW on the very next chunk/decode write
+                    # into it — the token-granular tail is registered once
+                    # at finish.
+                    full = r.prefill_done \
+                        - r.prefill_done % self.ecfg.page_size
+                    self.pool.register_prefix(r.req_id,
+                                              r.prompt_tokens[:full])
+                if r.remaining_prefill == 0:
+                    r.output_tokens = [int(nxt[i])]
+                    r.generated = 1
+                    # a resumed request's client saw its first token
+                    # before the crash
+                    if r.first_token_time < 0:
+                        r.first_token_time = now
+                    if r.done:
+                        self._finish(r, now)
+                        finished.append(r)
+            s.set_metadata(finished=len(finished))
         return finished
 
     def _dispatch_mixed_group(self, group: List[PrefillLane]) -> Dict[int, int]:
@@ -643,41 +668,47 @@ class PagedRealEngine:
         all decode) and run ``mixed_step_paged``. Returns req_id -> the
         argmax next token of each lane's chunk-end logits; effect
         application is the caller's job (canonical split order)."""
-        S = self.runner.mixed_bucket_for(max(l.chunk for l in group))
-        B = self.runner.lane_bucket_for(len(group))
-        toks = np.zeros((B, S), np.int32)
-        starts = np.zeros(B, np.int32)
-        lens = np.zeros(B, np.int32)
-        dmask = np.zeros(B, bool)
-        rids: List[Optional[int]] = [None] * B
-        for i, l in enumerate(group):
-            if l.decode:
-                toks[i, 0] = l.req.output_tokens[-1]
-            else:
-                toks[i, :l.chunk] = \
-                    l.req.prompt_tokens[l.start:l.start + l.chunk]
-            starts[i] = l.start
-            lens[i] = l.chunk
-            dmask[i] = l.decode
-            rids[i] = l.req.req_id
-        batch = {"tokens": jnp.asarray(toks),
-                 "chunk_starts": jnp.asarray(starts),
-                 "chunk_lens": jnp.asarray(lens),
-                 "decode_mask": jnp.asarray(dmask)}
-        bt = jnp.asarray(self.pool.block_table_array(
-            rids, self.ecfg.max_blocks_per_req))
+        with span(spans.INPUTS, kind="mixed"):
+            S = self.runner.mixed_bucket_for(max(l.chunk for l in group))
+            B = self.runner.lane_bucket_for(len(group))
+            toks = np.zeros((B, S), np.int32)
+            starts = np.zeros(B, np.int32)
+            lens = np.zeros(B, np.int32)
+            dmask = np.zeros(B, bool)
+            rids: List[Optional[int]] = [None] * B
+            for i, l in enumerate(group):
+                if l.decode:
+                    toks[i, 0] = l.req.output_tokens[-1]
+                else:
+                    toks[i, :l.chunk] = \
+                        l.req.prompt_tokens[l.start:l.start + l.chunk]
+                starts[i] = l.start
+                lens[i] = l.chunk
+                dmask[i] = l.decode
+                rids[i] = l.req.req_id
+            batch = {"tokens": jnp.asarray(toks),
+                     "chunk_starts": jnp.asarray(starts),
+                     "chunk_lens": jnp.asarray(lens),
+                     "decode_mask": jnp.asarray(dmask)}
+            bt = jnp.asarray(self.pool.block_table_array(
+                rids, self.ecfg.max_blocks_per_req))
+            placement = jnp.asarray(self.placement)
+            source_ids = jnp.full((B,), self.engine_id, jnp.int32)
         t0 = time.perf_counter()
-        logits, self.pages, stats = self.runner.mixed_step(
-            batch, self.pages, bt, jnp.asarray(self.placement),
-            jnp.full((B,), self.engine_id, jnp.int32))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))   # sync point
+        with span(spans.LAUNCH + "mixed", lanes=len(group),
+                  tokens=int(lens.sum()), engine=self.engine_id):
+            logits, self.pages, stats = self.runner.mixed_step(
+                batch, self.pages, bt, placement, source_ids)
+        with span(spans.READBACK, kind="mixed"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))   # sync point
         if self.swap_cost is not None:
             self.swap_cost.observe_prefill(int(lens.sum()),
                                            time.perf_counter() - t0)
         self.prefill_dispatches += 1
         self.prefill_lanes_total += len(group)
         if stats is not None:
-            self.stats_log.append(jax.tree.map(np.asarray, stats))
+            with span(spans.STATS_READBACK):
+                self.stats_log.append(jax.tree.map(np.asarray, stats))
         return {l.req.req_id: int(nxt[i]) for i, l in enumerate(group)}
 
     def _run_mixed(self, plan: StepPlan, now: float) -> List[Request]:
@@ -692,64 +723,78 @@ class PagedRealEngine:
         for group in plan.mixed_groups:
             next_tok.update(self._dispatch_mixed_group(group))
         finished: List[Request] = []
-        for l in plan.prefill_lanes:
-            r = l.req
-            r.prefill_done += l.chunk
-            self.total_prefill_tokens += l.chunk
-            if self.sharing:
-                full = r.prefill_done - r.prefill_done % self.ecfg.page_size
-                self.pool.register_prefix(r.req_id, r.prompt_tokens[:full])
-            if r.remaining_prefill == 0:
-                r.output_tokens = [next_tok[r.req_id]]
-                r.generated = 1
-                if r.first_token_time < 0:
-                    r.first_token_time = now
-                if r.done:
+        with span(spans.COMMIT) as s:
+            for l in plan.prefill_lanes:
+                r = l.req
+                r.prefill_done += l.chunk
+                self.total_prefill_tokens += l.chunk
+                if self.sharing:
+                    full = r.prefill_done \
+                        - r.prefill_done % self.ecfg.page_size
+                    self.pool.register_prefix(r.req_id,
+                                              r.prompt_tokens[:full])
+                if r.remaining_prefill == 0:
+                    r.output_tokens = [next_tok[r.req_id]]
+                    r.generated = 1
+                    if r.first_token_time < 0:
+                        r.first_token_time = now
+                    if r.done:
+                        self._finish(r, now)
+                        finished.append(r)
+            for r in plan.decode:
+                r.output_tokens.append(next_tok[r.req_id])
+                r.generated += 1
+                self.total_decode_tokens += 1
+                if r.done or written_kv_len(r) + 1 >= self.ecfg.max_len:
                     self._finish(r, now)
                     finished.append(r)
-        for r in plan.decode:
-            r.output_tokens.append(next_tok[r.req_id])
-            r.generated += 1
-            self.total_decode_tokens += 1
-            if r.done or written_kv_len(r) + 1 >= self.ecfg.max_len:
-                self._finish(r, now)
-                finished.append(r)
+            s.set_metadata(finished=len(finished))
         return finished
 
     def _run_decode(self, decode_reqs: List[Request],
                     now: float) -> List[Request]:
         B = self.ecfg.max_batch
         lanes = decode_reqs[:B]
-        tokens = np.zeros(B, np.int32)
-        lengths = np.zeros(B, np.int32)
-        active = np.zeros(B, bool)
-        rids: List[Optional[int]] = [None] * B
-        for i, r in enumerate(lanes):
-            tokens[i] = r.output_tokens[-1]
-            lengths[i] = written_kv_len(r)
-            active[i] = True
-            rids[i] = r.req_id
-        bt = self.pool.block_table_array(rids, self.ecfg.max_blocks_per_req)
+        with span(spans.INPUTS, kind="decode"):
+            tokens = np.zeros(B, np.int32)
+            lengths = np.zeros(B, np.int32)
+            active = np.zeros(B, bool)
+            rids: List[Optional[int]] = [None] * B
+            for i, r in enumerate(lanes):
+                tokens[i] = r.output_tokens[-1]
+                lengths[i] = written_kv_len(r)
+                active[i] = True
+                rids[i] = r.req_id
+            bt = self.pool.block_table_array(rids,
+                                             self.ecfg.max_blocks_per_req)
+            tokens, lengths, bt, active = (
+                jnp.asarray(a) for a in (tokens, lengths, bt, active))
+            placement = jnp.asarray(self.placement)
+            source_ids = jnp.full((B,), self.engine_id, jnp.int32)
         t0 = time.perf_counter()
-        logits, self.pages, stats = self.runner.decode(
-            jnp.asarray(tokens), self.pages, jnp.asarray(lengths),
-            jnp.asarray(bt), jnp.asarray(active),
-            jnp.asarray(self.placement),
-            jnp.full((B,), self.engine_id, jnp.int32))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))   # sync point
+        with span(spans.LAUNCH + "decode", lanes=len(lanes),
+                  tokens=len(lanes), engine=self.engine_id):
+            logits, self.pages, stats = self.runner.decode(
+                tokens, self.pages, lengths, bt, active, placement,
+                source_ids)
+        with span(spans.READBACK, kind="decode"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))   # sync point
         self.decode_dispatches += 1
         if self.swap_cost is not None:
             self.swap_cost.observe_decode(time.perf_counter() - t0)
         if stats is not None:
-            self.stats_log.append(jax.tree.map(np.asarray, stats))
+            with span(spans.STATS_READBACK):
+                self.stats_log.append(jax.tree.map(np.asarray, stats))
         finished = []
-        for i, r in enumerate(lanes):
-            r.output_tokens.append(int(nxt[i]))
-            r.generated += 1
-            self.total_decode_tokens += 1
-            if r.done or written_kv_len(r) + 1 >= self.ecfg.max_len:
-                self._finish(r, now)
-                finished.append(r)
+        with span(spans.COMMIT) as s:
+            for i, r in enumerate(lanes):
+                r.output_tokens.append(int(nxt[i]))
+                r.generated += 1
+                self.total_decode_tokens += 1
+                if r.done or written_kv_len(r) + 1 >= self.ecfg.max_len:
+                    self._finish(r, now)
+                    finished.append(r)
+            s.set_metadata(finished=len(finished))
         return finished
 
     # ---- control-plane surface -------------------------------------------

@@ -40,8 +40,10 @@ from repro.core.traces import TraceTable
 from repro.ft.elastic import ElasticController
 from repro.ft.faults import FaultInjector, FaultPlan
 from repro.ft.health import EngineHealthMonitor, HealthConfig
+from repro.serving import spans
 from repro.serving.request import Request, RequestState
 from repro.serving.simulator import SimResult
+from repro.serving.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -252,29 +254,31 @@ def serve_real_cluster(requests: List[Request], engines, *,
         against the placement still serving), adoption is a pointer swap."""
         nonlocal cur_perms, staged, pointer_swaps
         from repro.models.transformer import migrate_params_for_placement
-        if staged is not None and np.array_equal(staged["perms"], new_perms) \
-                and np.array_equal(staged["base"], cur_perms):
-            for e in engines:
-                holder = getattr(e, "runner", e)
-                buf = staged["params"].get(id(holder))
-                holder.params = buf if buf is not None else \
-                    migrate_params_for_placement(
-                        holder.params, mcfg, cur_perms, new_perms)
-                e.placement = new_perms
-            cur_perms = new_perms
+        swap = staged is not None \
+            and np.array_equal(staged["perms"], new_perms) \
+            and np.array_equal(staged["base"], cur_perms)
+        with span(spans.MIGRATE, pointer_swap=int(swap)):
+            if swap:
+                for e in engines:
+                    holder = getattr(e, "runner", e)
+                    buf = staged["params"].get(id(holder))
+                    holder.params = buf if buf is not None else \
+                        migrate_params_for_placement(
+                            holder.params, mcfg, cur_perms, new_perms)
+                    e.placement = new_perms
+                pointer_swaps += 1
+            else:                  # stale buffer: fall back to a live move
+                seen = set()
+                for e in engines:
+                    # the runner (paged engines) or the engine itself
+                    holder = getattr(e, "runner", e)
+                    if id(holder) not in seen:
+                        seen.add(id(holder))
+                        holder.params = migrate_params_for_placement(
+                            holder.params, mcfg, cur_perms, new_perms)
+                    e.placement = new_perms
             staged = None
-            pointer_swaps += 1
-            return
-        staged = None              # stale buffer: fall back to a live move
-        seen = set()
-        for e in engines:
-            holder = getattr(e, "runner", e)   # runner (paged) or engine
-            if id(holder) not in seen:
-                seen.add(id(holder))
-                holder.params = migrate_params_for_placement(
-                    holder.params, mcfg, cur_perms, new_perms)
-            e.placement = new_perms
-        cur_perms = new_perms
+            cur_perms = new_perms
 
     if coord is not None and cc.prefetch:
         coord.on_prefetch = stage_prefetch
@@ -283,10 +287,11 @@ def serve_real_cluster(requests: List[Request], engines, *,
         # delta-based prefix digests: ship a full summary only when the
         # table lost the chain (first report, engine restart, scheduler
         # include()) — steady-state traces carry deltas
-        table.report(e.trace(now, full_prefix_summary=table.needs_resync(
-            e.engine_id)), now=now)
-        if hasattr(sched, "on_trace_refresh"):
-            sched.on_trace_refresh(e.engine_id)
+        with span(spans.REPORT, engine=e.engine_id):
+            table.report(e.trace(now, full_prefix_summary=table.needs_resync(
+                e.engine_id)), now=now)
+            if hasattr(sched, "on_trace_refresh"):
+                sched.on_trace_refresh(e.engine_id)
 
     # per-engine drained-finish watermark for the streaming metrics hook
     # (engine restarts keep their finished list, so watermarks only grow;
@@ -324,49 +329,12 @@ def serve_real_cluster(requests: List[Request], engines, *,
     def is_dead(e) -> bool:
         return getattr(e, "dead", False)
 
-    while (pending or orphans or crash_exports
-           or any(e.has_work for e in engines)) and rounds < cc.max_rounds:
-        # ---- 1. scheduled faults (deterministic chaos) -------------------
-        if injector is not None:
-            for eid in injector.crashes(rounds):
-                e = by_id[eid]
-                if not is_dead(e):
-                    crash_exports.setdefault(eid, []).extend(e.fail(now))
-            for eid in injector.recoveries(rounds):
-                e = by_id[eid]
-                if is_dead(e):
-                    e.restart()
-                    # exports never detected (quick recovery) re-dispatch
-                    # now — the restarted engine lost its pool regardless
-                    orphans.extend(crash_exports.pop(eid, []))
-                    if eid not in table.engine_ids:   # drained: rejoin
-                        ec.scale_up(eid, now)
-            for eid in injector.drains(rounds):
-                e = by_id[eid]
-                if not is_dead(e) and not getattr(e, "draining", False):
-                    sched.exclude(eid)
-                    moved = e.drain(now)
-                    tier = getattr(e, "tier", None)
-                    if tier is not None:
-                        drain_swapped[eid] = sum(
-                            1 for r in moved
-                            if tier.holds_request(r.req_id))
-                    orphans.extend(moved)
-            for e in engines:
-                if hasattr(e, "pool"):
-                    e.pool.force_alloc_fail = injector.alloc_fail(
-                        e.engine_id, rounds)
-
-        # drain completion: residents finished -> release pool, leave fleet
-        for e in engines:
-            if getattr(e, "draining", False) and not e.has_work:
-                e.release()
-                drained_engines.append(e.engine_id)
-                if e.engine_id in table.engine_ids:
-                    ec.scale_down(e.engine_id, now, drain=lambda _: 0,
-                                  swapped=drain_swapped.pop(e.engine_id, 0))
-                mon.unhealthy.discard(e.engine_id)
-
+    def dispatch() -> int:
+        """Steps 2 and 3 of a round: Algorithm 1 places the arrivals due
+        by now and the orphans due for re-dispatch. Returns how many
+        requests were placed."""
+        nonlocal orphans, shed, recovered, recovery_recompute_tokens
+        placed = 0
         # ---- 2. dispatch arrivals due by now (Algorithm 1 against live
         # traces; prompt ids let the scheduler score prefix affinity
         # against the engines' radix-cache summaries). Under cluster-wide
@@ -393,6 +361,7 @@ def serve_real_cluster(requests: List[Request], engines, *,
                 break
             pending.pop(0)
             by_id[eid].enqueue(r, now)
+            placed += 1
 
         # ---- 3. re-dispatch recovered requests (capped backoff) ----------
         if orphans:
@@ -419,6 +388,7 @@ def serve_real_cluster(requests: List[Request], engines, *,
                     still.append(r)
                     continue
                 by_id[eid].enqueue(r, now)
+                placed += 1
                 if not r.error:            # target may reject at enqueue
                     recovered += 1
                     # tokens this request will prefill again: tier-backed
@@ -428,76 +398,131 @@ def serve_real_cluster(requests: List[Request], engines, *,
                     recovery_recompute_tokens += max(
                         r.prompt_len - r.prefill_done, 0)
             orphans = still
+        return placed
 
-        # ---- 4. step the data planes + collect traces --------------------
-        for e in engines:
-            if is_dead(e):
-                continue       # no steps, no traces: staleness will tell
-            straggling = injector is not None and injector.skip_step(
-                e.engine_id, rounds)
-            if not straggling:
-                e.step(now)
-            if not (injector is not None
-                    and injector.drop_trace(e.engine_id, rounds)):
-                report_trace(e)
-            kv_peak = max(kv_peak, e.pool.usage) \
-                if hasattr(e, "pool") else kv_peak
-            if coord is not None:
-                B, A = e.window_stats()
-                if B is not None:
-                    coord.profiler.record_step(
-                        B, A, n_tokens=int(B.sum())
-                        // max(mcfg.n_moe_layers, 1)
-                        // max(mcfg.moe.top_k, 1))
-
-        drain_finishes()
-
-        # ---- 5. health: exclude+fence stale engines, rejoin fresh ones ---
-        mon.check(now)
-
-        if coord is not None:
-            migrated, _dur = coord.maybe_rebalance(now)
-            if migrated:
-                migrations += 1
-            if coord.poll_prefetch(now):
-                migrations += 1    # a flip is still a placement adoption
-            perms = np.asarray(coord.placement.permutations())
-            if not np.array_equal(perms, cur_perms):
-                apply_placement(perms)
-            if coord._last_rank_load.sum() > 0:
+    while (pending or orphans or crash_exports
+           or any(e.has_work for e in engines)) and rounds < cc.max_rounds:
+        with span(spans.ROUND, round=rounds):
+            # ---- 1. scheduled faults (deterministic chaos) ------------------
+            if injector is not None:
+                for eid in injector.crashes(rounds):
+                    e = by_id[eid]
+                    if not is_dead(e):
+                        crash_exports.setdefault(eid, []).extend(e.fail(now))
+                for eid in injector.recoveries(rounds):
+                    e = by_id[eid]
+                    if is_dead(e):
+                        e.restart()
+                        # exports never detected (quick recovery) re-dispatch
+                        # now — the restarted engine lost its pool regardless
+                        orphans.extend(crash_exports.pop(eid, []))
+                        if eid not in table.engine_ids:   # drained: rejoin
+                            ec.scale_up(eid, now)
+                for eid in injector.drains(rounds):
+                    e = by_id[eid]
+                    if not is_dead(e) and not getattr(e, "draining", False):
+                        sched.exclude(eid)
+                        moved = e.drain(now)
+                        tier = getattr(e, "tier", None)
+                        if tier is not None:
+                            drain_swapped[eid] = sum(
+                                1 for r in moved
+                                if tier.holds_request(r.req_id))
+                        orphans.extend(moved)
                 for e in engines:
-                    e.moe_pressure = coord.engine_moe_pressure(e.engine_id)
+                    if hasattr(e, "pool"):
+                        e.pool.force_alloc_fail = injector.alloc_fail(
+                            e.engine_id, rounds)
 
-        # ---- 6. livelock watchdog (opt-in) -------------------------------
-        if cc.stall_abort_rounds > 0:
-            marker = progress_marker()
-            if rounds > 0 and marker == last_marker:
-                stall_streak += 1
-                if stall_streak >= cc.stall_abort_rounds:
-                    for e in engines:
-                        if hasattr(e, "fail") and e.has_work:
-                            orphans.extend(e.fail(now))
-                    for r in (orphans + pending
-                              + [q for v in crash_exports.values()
-                                 for q in v]):
-                        quarantine(r, "cluster_livelock")
-                    orphans, pending = [], []
-                    crash_exports.clear()
-                    break
-            else:
-                stall_streak = 0
-            last_marker = marker
-        elif rounds == 0:
-            last_marker = progress_marker()
+            # drain completion: residents finished -> release pool, leave fleet
+            for e in engines:
+                if getattr(e, "draining", False) and not e.has_work:
+                    e.release()
+                    drained_engines.append(e.engine_id)
+                    if e.engine_id in table.engine_ids:
+                        ec.scale_down(
+                            e.engine_id, now, drain=lambda _: 0,
+                            swapped=drain_swapped.pop(e.engine_id, 0))
+                    mon.unhealthy.discard(e.engine_id)
 
-        # ---- 7. periodic control-plane snapshot --------------------------
-        if cc.snapshot_every_rounds > 0 and cc.snapshot_path \
-                and rounds > 0 and rounds % cc.snapshot_every_rounds == 0:
-            _save_cluster_state(cc.snapshot_path, sched, coord, table,
-                                rounds)
+            # ---- 2-3. dispatch: due arrivals, then recovered requests
+            with span(spans.DISPATCH) as s:
+                s.set_metadata(n=dispatch())
 
-        now += cc.dt
-        rounds += 1
+            # ---- 4. step the data planes + collect traces -------------------
+            for e in engines:
+                if is_dead(e):
+                    continue       # no steps, no traces: staleness will tell
+                straggling = injector is not None and injector.skip_step(
+                    e.engine_id, rounds)
+                if not straggling:
+                    e.step(now)
+                if not (injector is not None
+                        and injector.drop_trace(e.engine_id, rounds)):
+                    report_trace(e)
+                kv_peak = max(kv_peak, e.pool.usage) \
+                    if hasattr(e, "pool") else kv_peak
+                if coord is not None:
+                    with span(spans.STATS, engine=e.engine_id):
+                        B, A = e.window_stats()
+                        if B is not None:
+                            coord.profiler.record_step(
+                                B, A, n_tokens=int(B.sum())
+                                // max(mcfg.n_moe_layers, 1)
+                                // max(mcfg.moe.top_k, 1))
+
+            drain_finishes()
+
+            # ---- 5. health: exclude+fence stale engines, rejoin fresh ones --
+            mon.check(now)
+
+            if coord is not None:
+                with span(spans.REBALANCE) as s:
+                    adopted = migrations
+                    migrated, _dur = coord.maybe_rebalance(now)
+                    if migrated:
+                        migrations += 1
+                    if coord.poll_prefetch(now):
+                        migrations += 1  # a flip is still an adoption
+                    s.set_metadata(migrated=migrations - adopted)
+                    perms = np.asarray(coord.placement.permutations())
+                    if not np.array_equal(perms, cur_perms):
+                        apply_placement(perms)
+                    if coord._last_rank_load.sum() > 0:
+                        for e in engines:
+                            e.moe_pressure = coord.engine_moe_pressure(
+                                e.engine_id)
+
+            # ---- 6. livelock watchdog (opt-in) ------------------------------
+            if cc.stall_abort_rounds > 0:
+                marker = progress_marker()
+                if rounds > 0 and marker == last_marker:
+                    stall_streak += 1
+                    if stall_streak >= cc.stall_abort_rounds:
+                        for e in engines:
+                            if hasattr(e, "fail") and e.has_work:
+                                orphans.extend(e.fail(now))
+                        for r in (orphans + pending
+                                  + [q for v in crash_exports.values()
+                                     for q in v]):
+                            quarantine(r, "cluster_livelock")
+                        orphans, pending = [], []
+                        crash_exports.clear()
+                        break
+                else:
+                    stall_streak = 0
+                last_marker = marker
+            elif rounds == 0:
+                last_marker = progress_marker()
+
+            # ---- 7. periodic control-plane snapshot -------------------------
+            if cc.snapshot_every_rounds > 0 and cc.snapshot_path \
+                    and rounds > 0 and rounds % cc.snapshot_every_rounds == 0:
+                _save_cluster_state(cc.snapshot_path, sched, coord, table,
+                                    rounds)
+
+            now += cc.dt
+            rounds += 1
 
     drain_finishes()
     # rejected/shed/quarantined requests (error set) must not pollute the
