@@ -2,17 +2,22 @@
 
 The KV cache lives in a physical page pool ``(n_pages, page_size, Hkv, hd)``
 shared by every request; each request owns a *block table* — the ordered list
-of page ids holding its context. The kernel extends ``flash_decode``'s
-running-softmax structure: grid (B, n_blocks) iterates a request's logical
-pages sequentially, the block table rides in SMEM via scalar prefetch so the
-K/V BlockSpec index maps fetch physical page ``bt[b, j]`` directly from HBM —
-no gather materialisation, working set one (page_size, Hkv, hd) tile.
+of page ids holding its context. The kernel keeps ``flash_decode``'s
+running softmax and walks only each lane's live pages: the grid is one step
+per lane, and a lane loops over blocks of ``pages_per_block`` consecutive
+block-table slots (~256 tokens) up to its ``ctx_lens``. The block table and
+``ctx_lens`` ride in SMEM via scalar prefetch; the pool stays in HBM, and each
+live page of a block is copied into a double-buffered VMEM block by its own
+DMA. The next block's copies start before this block is computed, and a
+lane's last block starts the first block of the next lane with a context, so
+the DMAs run ahead across lanes. Work per call scales with the live pages,
+not with lanes x table slots; slots past the context are never fetched.
 
 Conventions shared with ``serving/paged.py``:
 
 * page id 0 is the reserved garbage page — allocators never hand it out, and
   masked/inactive writes land there;
-* unused block-table entries are 0 (valid index, masked by ``ctx_lens``);
+* unused block-table entries are 0; nothing past ``ctx_lens`` is read;
 * ``ctx_lens[b]`` is the number of live tokens — rows with ``ctx_lens == 0``
   produce a zero output vector.
 
@@ -30,61 +35,116 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+BLOCK_TOKENS = 256          # tokens per block of a lane's walk
 
 
-def _kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *rest,
-            scale, page_size, n_blocks, quant):
-    # args after v_ref: [k_scale_ref, v_scale_ref (quant only)], o_ref,
-    # then the three scratch buffers
+def pages_per_block(page_size: int, n_slots: int) -> int:
+    """Block-table slots walked per block: ~``BLOCK_TOKENS`` tokens, never
+    more than the table holds."""
+    return max(1, min(n_slots, BLOCK_TOKENS // page_size))
+
+
+def _kernel(bt_ref, ctx_ref, nxt_ref, q_ref, k_hbm, v_hbm, *rest,
+            scale, ppb, quant):
     if quant:
-        ks_ref, vs_ref, o_ref = rest[0], rest[1], rest[2]
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sems, slot_ref = rest
     else:
-        o_ref = rest[0]
-    m_scr, l_scr, acc_scr = rest[-3], rest[-2], rest[-1]
-    j = pl.program_id(1)
+        o_ref, k_buf, v_buf, sems, slot_ref = rest
+    b = pl.program_id(0)
+    n_lanes = pl.num_programs(0)
+    _, _, ps, Hkv, hd = k_buf.shape
+    bk = ppb * ps                                 # tokens per block
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def dmas(lane, blk, slot, i):
+        """The K and V copies of page ``i`` of block ``blk`` into buffer
+        ``slot``."""
+        page = bt_ref[lane, blk * ppb + i]
+        return [pltpu.make_async_copy(src.at[page], dst.at[slot, i],
+                                      sems.at[kv, slot])
+                for kv, (src, dst) in enumerate([(k_hbm, k_buf),
+                                                 (v_hbm, v_buf)])]
 
-    q = q_ref[0]                                  # (Hq, hd)
-    k = k_ref[0].astype(jnp.float32)              # (ps, Hkv, hd)
-    v = v_ref[0].astype(jnp.float32)
-    if quant:                                     # int8 pages: dequant on read
-        k = k * ks_ref[0][..., None]              # scales (ps, Hkv)
-        v = v * vs_ref[0][..., None]
-    ctx = ctx_ref[pl.program_id(0)]               # scalar: live tokens
+    def live_pages(lane, blk):          # pages past the context: never read
+        return jnp.minimum(ppb, pl.cdiv(ctx_ref[lane] - blk * bk, ps))
 
-    Hq, hd = q.shape
-    ps, Hkv, _ = k.shape
+    def start(lane, blk, slot):
+        @pl.loop(0, live_pages(lane, blk))
+        def _(i):
+            for c in dmas(lane, blk, slot, i):
+                c.start()
+
+    def wait(lane, blk, slot):
+        @pl.loop(0, live_pages(lane, blk))
+        def _(i):
+            for c in dmas(lane, blk, slot, i):
+                c.wait()
+
+    @pl.when(b == nxt_ref[0])         # the first live lane fetches its own
+    def _first():                     # first block; later lanes find theirs
+        slot_ref[0] = 0               # in flight (started by the lane before)
+        start(b, 0, 0)
+
+    ctx = ctx_ref[b]
+    n_blocks = pl.cdiv(ctx, bk)
+    next_lane = nxt_ref[b + 1]        # first live lane after b (or n_lanes)
+    Hq = q_ref.shape[1]
     G = Hq // Hkv
-    qg = q.reshape(Hkv, G, hd)
+    q = q_ref[0]                                  # (Hq, hd)
+    # bf16 x bf16 products are exact in f32: Q.K^T may take bf16 operands
+    dt = jnp.float32 if quant else jnp.promote_types(q.dtype, k_buf.dtype)
 
-    # logical positions covered by this page; mask dead tail + garbage pages
-    kpos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, (ps,), 0)
-    valid = kpos < ctx
+    def block(i, carry):
+        m_prev, l_prev, acc = carry     # (Hkv, G), (Hkv, G), (Hkv, G, hd)
+        slot = slot_ref[0]
 
-    s = jnp.einsum("kgd,lkd->kgl", qg.astype(jnp.float32),
-                   k) * scale                          # (Hkv, G, ps)
-    s = jnp.where(valid[None, None, :], s, NEG_INF)
+        @pl.when(i + 1 < n_blocks)
+        def _():
+            start(b, i + 1, 1 - slot)
 
-    m_prev = m_scr[...]                                # (Hkv, G)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[..., None])
-    p = jnp.where(valid[None, None, :], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_scr[...] * corr + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * corr[..., None] + jnp.einsum(
-        "kgl,lkd->kgd", p, v)
-    m_scr[...] = m_new
-    l_scr[...] = l_new
+        @pl.when((i + 1 == n_blocks) & (next_lane < n_lanes))
+        def _():
+            start(next_lane, 0, 1 - slot)
 
-    @pl.when(j == n_blocks - 1)
-    def _finish():
-        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)[..., None]
-        o_ref[0] = out.reshape(Hq, hd).astype(o_ref.dtype)
+        slot_ref[0] = 1 - slot
+        wait(b, i, slot)
+
+        # slots past the context were not fetched (stale VMEM): select, never
+        # multiply, them away
+        live = i * bk + jax.lax.broadcasted_iota(jnp.int32, (G, bk), 1) < ctx
+        vlive = i * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, hd), 0) < ctx
+        k_blk = k_buf[slot].reshape(bk, Hkv, hd)
+        v_blk = v_buf[slot].reshape(bk, Hkv, hd)
+        if quant:                   # int8 pages: scales (Hkv, bk) of the block
+            off = 0 if ks_ref.shape[-1] == bk else pl.multiple_of(i * bk, bk)
+            k_sc = ks_ref[0, :, pl.ds(off, bk)]
+            v_sc = vs_ref[0, :, pl.ds(off, bk)]
+        ms, ls, accs = [], [], []
+        for h in range(Hkv):            # G query heads share KV head h
+            s = jax.lax.dot_general(
+                q[h * G:(h + 1) * G].astype(dt), k_blk[:, h].astype(dt),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale      # (G, bk)
+            if quant:
+                s = s * k_sc[h:h + 1]
+            s = jnp.where(live, s, NEG_INF)
+            m = jnp.maximum(m_prev[h], jnp.max(s, axis=-1))
+            p = jnp.where(live, jnp.exp(s - m[:, None]), 0.0)
+            corr = jnp.exp(m_prev[h] - m)
+            ls.append(l_prev[h] * corr + jnp.sum(p, axis=-1))
+            if quant:
+                p = jnp.where(live, p * v_sc[h:h + 1], 0.0)
+            v = jnp.where(vlive, v_blk[:, h].astype(jnp.float32), 0.0)
+            accs.append(acc[h] * corr[:, None] + jnp.dot(
+                p, v, preferred_element_type=jnp.float32))
+            ms.append(m)
+        return jnp.stack(ms), jnp.stack(ls), jnp.stack(accs)
+
+    m0 = jnp.full((Hkv, G), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((Hkv, G), jnp.float32)
+    acc0 = jnp.zeros((Hkv, G, hd), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (m0, l0, acc0))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]  # ctx == 0: zeros
+    o_ref[0] = out.reshape(Hq, hd).astype(o_ref.dtype)
 
 
 def paged_decode_pallas(q, k_pages, v_pages, block_tables, ctx_lens, *,
@@ -92,52 +152,53 @@ def paged_decode_pallas(q, k_pages, v_pages, block_tables, ctx_lens, *,
                         interpret: bool = False):
     """q (B, Hq, hd); pages (P, ps, Hkv, hd); block_tables (B, NB) int32
     physical page ids (0-filled past the context); ctx_lens (B,) int32.
-    ``k_scales``/``v_scales`` (P, ps, Hkv) fp32 mark int8 pages — the
-    kernel dequantizes each fetched page tile in-register (kv_pack.py)."""
+    ``k_scales``/``v_scales`` (P, ps, Hkv) fp32 mark int8 pages
+    (kv_pack.py): each token's scales multiply its scores and its
+    probabilities in-kernel."""
     B, Hq, hd = q.shape
     P, ps, Hkv, _ = k_pages.shape
     _, NB = block_tables.shape
-    scale = 1.0 / np.sqrt(hd)
-    G = Hq // Hkv
-    bt = block_tables.astype(jnp.int32)
+    ppb = pages_per_block(ps, NB)
+    pad = -NB % ppb
+    bt = jnp.pad(block_tables.astype(jnp.int32), ((0, 0), (0, pad)))
+    ctx = jnp.minimum(ctx_lens.astype(jnp.int32), NB * ps)
+    # nxt[0]: first lane with a context; nxt[b + 1]: first such lane after b
+    lane = jnp.where(ctx > 0, jnp.arange(B, dtype=jnp.int32), B)
+    nxt = jnp.append(jax.lax.cummin(lane, reverse=True), B).astype(jnp.int32)
     quant = k_scales is not None
 
-    kernel = functools.partial(_kernel, scale=scale, page_size=ps,
-                               n_blocks=NB, quant=quant)
-    # index maps see (b, j, block table, ctx_lens): both ride in SMEM via
-    # scalar prefetch (a (1, 1) VMEM block of ctx_lens would break the TPU
-    # tiling rule on the last two block dims)
-    in_specs = [
-        pl.BlockSpec((1, Hq, hd), lambda b, j, bt, cl: (b, 0, 0)),
-        pl.BlockSpec((1, ps, Hkv, hd),
-                     lambda b, j, bt, cl: (bt[b, j], 0, 0, 0)),
-        pl.BlockSpec((1, ps, Hkv, hd),
-                     lambda b, j, bt, cl: (bt[b, j], 0, 0, 0)),
-    ]
-    args = [bt, ctx_lens.astype(jnp.int32), q, k_pages, v_pages]
+    kernel = functools.partial(_kernel, scale=1.0 / np.sqrt(hd), ppb=ppb,
+                               quant=quant)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [pl.BlockSpec((1, Hq, hd), lambda b, *_: (b, 0, 0)), hbm, hbm]
+    args = [bt, ctx, nxt, q, k_pages, v_pages]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, ps, Hkv),
-                         lambda b, j, bt, cl: (bt[b, j], 0, 0)),
-            pl.BlockSpec((1, ps, Hkv),
-                         lambda b, j, bt, cl: (bt[b, j], 0, 0)),
-        ]
-        args += [k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)]
+        # a page's (ps, Hkv) scales are narrower than a vector lane row, which
+        # no DMA can slice: gather each lane's scales, token-major per KV head
+        # (B, Hkv, NB' * ps), into VMEM beside its lane
+        L = bt.shape[1] * ps
+        for sc in (k_scales, v_scales):
+            args.append(sc.astype(jnp.float32)[bt].transpose(0, 3, 1, 2)
+                        .reshape(B, Hkv, L))
+            in_specs.append(pl.BlockSpec((1, Hkv, L), lambda b, *_: (b, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                    # block table, ctx_lens
-        grid=(B, NB),
+        num_scalar_prefetch=3,                # block table, ctx_lens, nxt
+        grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, hd), lambda b, j, bt, cl: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, hd), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Hkv, G), jnp.float32),        # running max m
-            pltpu.VMEM((Hkv, G), jnp.float32),        # running sum l
-            pltpu.VMEM((Hkv, G, hd), jnp.float32),    # output accumulator
+            pltpu.VMEM((2, ppb, ps, Hkv, hd), k_pages.dtype),
+            pltpu.VMEM((2, ppb, ps, Hkv, hd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # (K or V, buffer slot)
+            pltpu.SMEM((1,), jnp.int32),      # slot of the block in flight
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),   # lanes hand DMAs on
         interpret=interpret,
         name="paged_decode",        # the custom call's name in traces
     )(*args)

@@ -29,28 +29,78 @@ def _random_block_setup(B, P, ps, NB, ctx_lens, rng):
 
 
 # ------------------------------------------------------------ kernel parity
-@pytest.mark.parametrize("B,Hq,Hkv,hd,ps,NB", [
-    (2, 4, 4, 32, 16, 4),     # MHA
-    (3, 8, 2, 16, 8, 5),      # GQA 4:1
-    (4, 4, 1, 64, 32, 3),     # MQA, bigger pages
+def _case(B, Hq, Hkv, hd, ps, NB, ctx=None, dtype=jnp.float32, *, id):
+    return pytest.param(B, Hq, Hkv, hd, ps, NB, ctx, dtype, id=id)
+
+
+# ctx None: ragged lengths incl. an empty lane and a page-aligned boundary.
+# The kernel walks blocks of pages_per_block(ps, NB) slots (16 at ps 16,
+# 8 at ps 32 once NB allows); ctx 256 ends a block exactly.
+@pytest.mark.parametrize("B,Hq,Hkv,hd,ps,NB,ctx,dtype", [
+    _case(2, 4, 4, 32, 16, 4, id="2-4-4-32-16-4"),         # MHA
+    _case(3, 8, 2, 16, 8, 5, id="3-8-2-16-8-5"),           # GQA 4:1
+    _case(4, 4, 1, 64, 32, 3, id="4-4-1-64-32-3"),         # MQA, bigger pages
+    # GQA 8:1 as in Qwen3; NB 20 is not a multiple of its 16-page blocks
+    _case(5, 32, 4, 16, 16, 20, [0, 16, 256, 320, 257], id="gqa8-bf16",
+          dtype=jnp.bfloat16),
+    _case(5, 32, 4, 16, 16, 20, [255, 0, 320, 17, 256], id="gqa8-f32"),
+    # NB 3 is smaller than a block: one block of 3 slots, full table
+    _case(4, 32, 4, 16, 16, 3, [48, 0, 16, 47], id="nb-below-block-bf16",
+          dtype=jnp.bfloat16),
+    # 8-page blocks of 256 tokens, 12 slots; empty lanes between live ones
+    _case(6, 8, 2, 16, 32, 12, [0, 384, 0, 0, 256, 33], id="blocks-f32"),
+    _case(6, 8, 2, 16, 32, 12, [256, 0, 0, 384, 1, 0], id="blocks-bf16",
+          dtype=jnp.bfloat16),
 ])
-def test_paged_decode_parity_sweep(B, Hq, Hkv, hd, ps, NB):
+def test_paged_decode_parity_sweep(B, Hq, Hkv, hd, ps, NB, ctx, dtype):
     P = B * NB + 4
     rng = np.random.default_rng(3)
-    q = jnp.asarray(rng.normal(size=(B, Hq, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, ps, Hkv, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, ps, Hkv, hd)), jnp.float32)
-    # ragged lengths incl. an empty lane and a page-aligned boundary
-    ctx = np.minimum([0, 1, ps, NB * ps - 3][:B] or [5], NB * ps)
+    q = jnp.asarray(rng.normal(size=(B, Hq, hd)), dtype)
+    kp = jnp.asarray(rng.normal(size=(P, ps, Hkv, hd)), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, ps, Hkv, hd)), dtype)
+    if ctx is None:
+        ctx = np.minimum([0, 1, ps, NB * ps - 3][:B] or [5], NB * ps)
     ctx = jnp.asarray(np.resize(ctx, B), jnp.int32)
     bt = _random_block_setup(B, P, ps, NB, np.asarray(ctx), rng)
 
     o_ref = ref.paged_decode_ref(q, kp, vp, bt, ctx)
     o_pal = paged_decode_pallas(q, kp, vp, bt, ctx, interpret=True)
     o_xla = paged_decode_xla(q, kp, vp, bt, ctx)
+    tol = 1e-5 if dtype == jnp.float32 else 1e-2    # one bf16 output ulp
+    for got in (o_pal, o_xla):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(o_ref, np.float32),
+                                   rtol=tol, atol=tol)
+    assert not np.asarray(o_pal[np.asarray(ctx) == 0]).any()
+
+
+@pytest.mark.parametrize("ps,NB", [(16, 20), (32, 12)])
+def test_paged_decode_never_reads_dead_pages(ps, NB):
+    """Page 0, every page wholly past a lane's context and the rows past
+    the context in its last page hold NaN: the kernel must neither fetch
+    nor multiply them, so its output is finite and equals the reference
+    computed on a pool whose dead rows are finite."""
+    B, Hq, Hkv, hd = 5, 8, 2, 16
+    P = B * NB + 4
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.normal(size=(B, Hq, hd)), jnp.float32)
+    kp = rng.normal(size=(P, ps, Hkv, hd)).astype(np.float32)
+    vp = rng.normal(size=(P, ps, Hkv, hd)).astype(np.float32)
+    ctx = np.array([NB * ps // 2 + 3, 0, ps, 1, NB * ps - ps - 1], np.int32)
+    bt = np.asarray(_random_block_setup(B, P, ps, NB, ctx, rng))
+    live = np.zeros((P, ps), bool)
+    for b in range(B):
+        for t in range(ctx[b]):
+            live[bt[b, t // ps], t % ps] = True
+    assert not live[0].any()
+    o_ref = ref.paged_decode_ref(q, jnp.asarray(kp), jnp.asarray(vp),
+                                 jnp.asarray(bt), jnp.asarray(ctx))
+    kp[~live], vp[~live] = np.nan, np.nan
+    o_pal = paged_decode_pallas(q, jnp.asarray(kp), jnp.asarray(vp),
+                                jnp.asarray(bt), jnp.asarray(ctx),
+                                interpret=True)
+    assert np.isfinite(np.asarray(o_pal)).all()
     np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(o_xla), np.asarray(o_ref),
                                rtol=1e-5, atol=1e-5)
 
 
