@@ -26,7 +26,7 @@ from harness import check, costs, trace, traffic
 from harness.driver import Driver, RecordingRunner, engine_class
 from harness.readings import Context
 from harness.spec import Cell
-from harness.weights import is_moe, make_params
+from harness.weights import make_params
 
 
 class _CompileCount:
@@ -60,28 +60,6 @@ class _GcTime:
 
     def stop(self) -> None:
         gc.callbacks.remove(self)
-
-
-def program_config(m: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig, MoEConfig
-    moe = is_moe(m)
-    if m.get("mlp_only_layers") or int(m.get("decoder_sparse_step", 1)) != 1:
-        raise ValueError("every layer must be alike (all MoE or all dense)")
-    return ModelConfig(
-        name=m["name"], family="moe" if moe else "dense",
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"],
-        d_ff=m["moe_intermediate_size"] if moe else m["intermediate_size"],
-        vocab_size=m["vocab_size"], head_dim=m["head_dim"],
-        moe=MoEConfig(n_experts=m["num_experts"],
-                      top_k=m["num_experts_per_tok"],
-                      d_expert=m["moe_intermediate_size"], moe_every=1)
-        if moe else MoEConfig(),
-        qk_norm=True, rope_theta=float(m["rope_theta"]),
-        norm_eps=float(m["rms_norm_eps"]),
-        tie_embeddings=bool(m["tie_word_embeddings"]), dtype="bfloat16")
 
 
 def engine_config(m: dict, mix: dict, interpret: bool):
@@ -170,7 +148,7 @@ class Setup:
         self.devices = devices_for(cell.chips, require_chip)
         self.kind = self.devices[0].device_kind
         self.peak = costs.peaks(self.kind) if require_chip else None
-        self.cfg = program_config(m)
+        self.cfg = cell.arch.program_config(m)
         self.ecfg = engine_config(m, cell.traffic,
                                   interpret=self.devices[0].platform != "tpu")
         self.n_engines = cell.chips        # one engine on each chip

@@ -25,6 +25,7 @@ from typing import Dict, List, Tuple
 import numpy as np
 
 from harness.driver import ReqRecord
+from harness.spec import arch_of
 
 
 def accounting(records: List[ReqRecord], vocab: int) -> Dict[str, int]:
@@ -78,12 +79,11 @@ def gap_stats(gaps: np.ndarray) -> Dict[str, float]:
 def logit_gaps(model: dict, seed: int, picked: List[ReqRecord],
                control: bool = False) -> Tuple[np.ndarray, np.ndarray]:
     """Per served token: reference best minus reference logit of the
-    served token, and (with ``control``) of the float8 control's token."""
-    from reference.qwen3 import served_logits
-    ref = served_logits(model, seed,
-                        [r.req.prompt_tokens for r in picked],
-                        [r.req.output_tokens for r in picked],
-                        control=control)
+    served token, and (with ``control``) of the control's token, by the
+    architecture's reference."""
+    ref = arch_of(model).served_logits(
+        model, seed, [r.req.prompt_tokens for r in picked],
+        [r.req.output_tokens for r in picked], control=control)
     best = np.concatenate(ref["best"])
     gap = best - np.concatenate(ref["served"])
     ctrl = best - np.concatenate(ref["control"]) if control else None

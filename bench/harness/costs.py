@@ -5,13 +5,17 @@ reports it; a chip that is not in the table is an error, never a
 default. Operations and bytes are computed from each call's real inputs
 (live context lengths, real routed rows), never from padded shapes, so a
 roofline share cannot pass 100% by counting work the algorithm does not
-need.
+need. What depends on the block (attention, the weight products of a
+token, the paged decode kernel) the configuration's architecture module
+counts, layer by layer.
 """
 from __future__ import annotations
 
 from typing import Dict, Optional, Sequence
 
 import numpy as np
+
+from harness.spec import arch_of
 
 # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM
 PEAKS: Dict[str, Dict[str, float]] = {
@@ -33,16 +37,9 @@ def min_time_s(ops: float, nbytes: float, peak: Dict[str, float]) -> float:
 
 def paged_decode_cost(ctx_lens: Sequence[int], m: dict,
                       itemsize: int = 2):
-    """One call of the paged decode kernel (one layer): attention of one
-    query token per lane over its live context. Bytes: the live K and V
-    tokens, the queries and the outputs."""
-    hq, hkv, hd = (m["num_attention_heads"], m["num_key_value_heads"],
-                   m["head_dim"])
-    ctx = np.asarray(ctx_lens, np.float64)
-    ops = float(np.sum(4.0 * hq * hd * ctx))
-    kv = float(np.sum(ctx)) * 2 * hkv * hd * itemsize
-    qo = len(ctx) * 2 * hq * hd * itemsize
-    return ops, kv + qo
+    """One call of the paged decode kernel (one layer) over each lane's
+    live context: (operations, bytes), as the architecture counts them."""
+    return arch_of(m).paged_decode_cost(ctx_lens, m, itemsize)
 
 
 def gmm_cost(rows: int, touched: int, d_in: int, d_out: int,
@@ -57,28 +54,13 @@ def gmm_cost(rows: int, touched: int, d_in: int, d_out: int,
     return ops, nbytes
 
 
-def _per_token_layer_flops(m: dict) -> float:
-    """Weight products of one token in one layer (attention projections
-    and the feed-forward, top-k experts only)."""
-    D, hd = m["hidden_size"], m["head_dim"]
-    q, kv = m["num_attention_heads"] * hd, m["num_key_value_heads"] * hd
-    proj = 2.0 * D * (q + 2 * kv) + 2.0 * q * D
-    if int(m.get("num_experts", 0)) > 0:
-        ffn = (2.0 * D * m["num_experts"]
-               + m["num_experts_per_tok"] * 6.0 * D
-               * m["moe_intermediate_size"])
-    else:
-        ffn = 6.0 * D * m["intermediate_size"]
-    return proj + ffn
-
-
 def decode_flops(ctx_lens: Sequence[int], m: dict) -> float:
     """Model FLOPs one decode call needs: one token per live lane, with
     attention over its context, and the head for each lane."""
-    L, hq, hd = m["num_hidden_layers"], m["num_attention_heads"], \
-        m["head_dim"]
+    arch = arch_of(m)
     ctx = np.asarray(ctx_lens, np.float64)
-    per = L * (_per_token_layer_flops(m) + 4.0 * hq * hd * ctx)
+    per = sum(arch.token_flops(m, layer) + arch.attention_flops(m, layer, ctx)
+              for layer in range(m["num_hidden_layers"]))
     return float(np.sum(per) + len(ctx) * 2.0 * m["hidden_size"]
                  * m["vocab_size"])
 
@@ -88,12 +70,13 @@ def prefill_flops(lanes: Sequence[tuple], m: dict) -> float:
     completes): a chunk of ``chunk`` prompt tokens at ``start``, causal
     attention over everything before each token, and the head only where
     the chunk completes the prompt."""
-    L, hq, hd = m["num_hidden_layers"], m["num_attention_heads"], \
-        m["head_dim"]
+    arch = arch_of(m)
     total = 0.0
     for start, n, completes in lanes:
-        attn = 4.0 * hq * hd * (n * start + n * (n + 1) / 2.0)
-        total += L * (n * _per_token_layer_flops(m) + attn)
+        ctx = start + np.arange(1, n + 1, dtype=np.float64)
+        for layer in range(m["num_hidden_layers"]):
+            total += n * arch.token_flops(m, layer) \
+                + float(np.sum(arch.attention_flops(m, layer, ctx)))
         if completes:
             total += 2.0 * m["hidden_size"] * m["vocab_size"]
     return total

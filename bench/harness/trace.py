@@ -6,11 +6,13 @@ ran, their ``XLA Modules`` line every program. The host plane
 ``/host:CPU`` holds the harness's own spans (``bench/...``, written with
 ``jax.profiler.TraceAnnotation``) on the same clock.
 
-Kernels are found under the names the trace shows today: the ragged
-grouped matmul as ``%moe_gmm_ragged...`` custom calls, the paged decode
-kernel as a ``tpu_custom_call`` whose first operand is the (lanes, slots)
-int32 block table and whose result is the (lanes, heads, head_dim)
-bfloat16 attention output.
+Kernels are found under the names the trace shows: the ragged grouped
+matmul as ``%moe_gmm_ragged...`` custom calls, the paged decode kernel as
+a ``tpu_custom_call`` whose first operand is the (lanes, slots) int32
+block table and whose result is the (lanes, heads, head_dim) bfloat16
+attention output, and any other ``tpu_custom_call`` that names its
+operation (``%mla_decode.7 = ...``, not ``%custom-call.7 = ...``) under
+that name without its numeric suffix.
 """
 from __future__ import annotations
 
@@ -29,22 +31,27 @@ _PAGED_DECODE = re.compile(
     r"^%\S+ = bf16\[\d+,\d+,\d+\]\{[^}]*\} custom-call\(s32\[\d+,\d+\]")
 
 
+def _op(op_name: str) -> str:
+    """``%fusion.12 = ...`` -> ``fusion``."""
+    head = op_name.split(" = ", 1)[0].lstrip("%")
+    return re.sub(r"[.\d]+$", "", head)
+
+
 def kernel_of(op_name: str) -> Optional[str]:
     if op_name.startswith("%moe_gmm_ragged"):
         return "moe_gmm"
-    if 'custom_call_target="tpu_custom_call"' in op_name \
-            and _PAGED_DECODE.match(op_name):
+    if 'custom_call_target="tpu_custom_call"' not in op_name:
+        return None
+    if _PAGED_DECODE.match(op_name):
         return "paged_decode"
-    return None
+    name = _op(op_name)
+    return name if name != "custom-call" else None
 
 
 def _short(op_name: str) -> str:
-    """``%fusion.12 = ...`` -> ``fusion``; kernels by their kernel name."""
-    k = kernel_of(op_name)
-    if k:
-        return k
-    head = op_name.split(" = ", 1)[0].lstrip("%")
-    return re.sub(r"[.\d]+$", "", head)
+    """An operation's name without its number; kernels by their kernel
+    name."""
+    return kernel_of(op_name) or _op(op_name)
 
 
 @dataclasses.dataclass

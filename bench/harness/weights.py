@@ -1,34 +1,37 @@
 """Seeded random weights, in the layout the program's param tree has.
 
-Each leaf is drawn from its own key, ``fold_in(fold_in(run_key, leaf),
-layer)``, so the whole tree is one jitted call for the program and the
-reference can make one layer at a time and get the same numbers. Scales
-follow the program's own initializers (truncated normal of std
-``1/sqrt(fan_in)``, embedding normal 0.02); norm scales, which the program
-keeps as an offset from one, are drawn at std 0.1 so that they matter.
-
-Layout (period one: every layer of a configuration is alike)::
-
-    embed/embedding (V, D), embed/lm_head (D, V)           bfloat16
-    blocks/pos0/ln_attn, ln_ffn (L, D)                     float32
-    blocks/pos0/attn/wq (L, D, Hq*hd), wk, wv (L, D, Hkv*hd),
-                    wo (L, Hq*hd, D)                       bfloat16
-    blocks/pos0/attn/q_norm, k_norm (L, hd)                float32
-    blocks/pos0/moe/router (L, D, E)                       float32
-    blocks/pos0/moe/w_gate, w_up (L, E, D, F), w_down (L, E, F, D)
-    blocks/pos0/mlp/w_gate, w_up (L, D, F), w_down (L, F, D)
-    final_norm (D,)                                        float32
+The configuration's architecture module (``bench/arch/<model_type>.py``)
+lists the leaves; this module draws them. Each leaf is drawn from its own
+key: a global leaf ``i`` from ``fold_in(run_key, i)``, layered leaf ``i``
+of layer ``l`` from ``fold_in(fold_in(run_key, 100 + i), l)``, counting
+global and layered leaves apart in the order the module lists them. So
+the whole tree is one jitted call for the program, and the reference can
+make one layer at a time and get the same numbers. A layered leaf is
+stacked over the layers it lists, under its stack's path, so layers that
+differ sit in stacks of their own.
 """
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from harness.spec import arch_of
+
 BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+class Leaf(NamedTuple):
+    path: str                   # in the tree; a layered leaf's in its stack
+    shape: Tuple[int, ...]      # a layered leaf's for one layer
+    dtype: object
+    kind: str                   # "trunc" (within two std) or "normal"
+    std: float
+    layers: Optional[Tuple[int, ...]] = None   # stacked over; None: global
+    stack: str = ""             # path of the stack in the tree
 
 
 def run_key(seed: int):
@@ -39,43 +42,11 @@ def run_key(seed: int):
                                     impl="threefry2x32")
 
 
-def is_moe(m: dict) -> bool:
-    return int(m.get("num_experts", 0)) > 0
-
-
-def layer_leaves(m: dict) -> List[Tuple[str, Tuple[int, ...], object, str,
-                                        float]]:
-    """(path, per-layer shape, dtype, kind, std) of every layered leaf."""
-    D, hd = m["hidden_size"], m["head_dim"]
-    q, kv = m["num_attention_heads"] * hd, m["num_key_value_heads"] * hd
-    s = lambda n: 1.0 / np.sqrt(n)
-    out = [("ln_attn", (D,), F32, "normal", 0.1),
-           ("ln_ffn", (D,), F32, "normal", 0.1),
-           ("attn/wq", (D, q), BF16, "trunc", s(D)),
-           ("attn/wk", (D, kv), BF16, "trunc", s(D)),
-           ("attn/wv", (D, kv), BF16, "trunc", s(D)),
-           ("attn/wo", (q, D), BF16, "trunc", s(q)),
-           ("attn/q_norm", (hd,), F32, "normal", 0.1),
-           ("attn/k_norm", (hd,), F32, "normal", 0.1)]
-    if is_moe(m):
-        E, F = m["num_experts"], m["moe_intermediate_size"]
-        out += [("moe/router", (D, E), F32, "trunc", s(D)),
-                ("moe/w_gate", (E, D, F), BF16, "trunc", s(D)),
-                ("moe/w_up", (E, D, F), BF16, "trunc", s(D)),
-                ("moe/w_down", (E, F, D), BF16, "trunc", s(F))]
-    else:
-        F = m["intermediate_size"]
-        out += [("mlp/w_gate", (D, F), BF16, "trunc", s(D)),
-                ("mlp/w_up", (D, F), BF16, "trunc", s(D)),
-                ("mlp/w_down", (F, D), BF16, "trunc", s(F))]
-    return out
-
-
-def global_leaves(m: dict):
-    D, V = m["hidden_size"], m["vocab_size"]
-    return [("embed/embedding", (V, D), BF16, "normal", 0.02),
-            ("embed/lm_head", (D, V), BF16, "trunc", 1.0 / np.sqrt(D)),
-            ("final_norm", (D,), F32, "normal", 0.1)]
+def _leaves(m: dict) -> Tuple[List[Leaf], List[Leaf]]:
+    """(global leaves, layered leaves) of the configuration."""
+    out = arch_of(m).leaves(m)
+    return ([lf for lf in out if lf.layers is None],
+            [lf for lf in out if lf.layers is not None])
 
 
 def _draw(key, shape, dtype, kind, std):
@@ -115,33 +86,35 @@ def _set(tree: Dict, path: str, value) -> None:
 
 def make_params(m: dict, seed: int):
     """The program's whole param tree for ``seed``, in one jitted call."""
-    L = m["num_hidden_layers"]
+    glob, layered = _leaves(m)
 
     def build(key):
         tree: Dict = {}
-        for i, (path, shape, dt, kind, std) in enumerate(global_leaves(m)):
-            _set(tree, path, _draw(_leaf_key(key, i), shape, dt, kind, std))
-        pos0: Dict = {}
-        for i, (path, shape, dt, kind, std) in enumerate(layer_leaves(m)):
-            one = lambda l, i=i, shape=shape, dt=dt, kind=kind, std=std: \
-                layer_leaf(key, i, l, shape, dt, kind, std)
-            _set(pos0, path, jax.vmap(one)(jnp.arange(L)))
-        tree["blocks"] = {"pos0": pos0}
+        for i, lf in enumerate(glob):
+            _set(tree, lf.path,
+                 _draw(_leaf_key(key, i), lf.shape, lf.dtype, lf.kind,
+                       lf.std))
+        for i, lf in enumerate(layered):
+            one = lambda l, i=i, lf=lf: layer_leaf(
+                key, i, l, lf.shape, lf.dtype, lf.kind, lf.std)
+            _set(tree, lf.stack + "/" + lf.path,
+                 jax.vmap(one)(jnp.asarray(lf.layers, jnp.int32)))
         return tree
 
     return jax.jit(build)(run_key(seed))
 
 
 def make_layer(m: dict, seed: int, layer: int) -> Dict[str, jax.Array]:
-    """One layer's leaves, flat by path, equal to ``make_params``'s slice."""
+    """One layer's leaves, flat by their path in the stack, equal to
+    ``make_params``'s slices."""
     key = run_key(seed)
-    return {path: _one_layer_leaf(key, layer, i, shape, dt, kind, std)
-            for i, (path, shape, dt, kind, std)
-            in enumerate(layer_leaves(m))}
+    return {lf.path: _one_layer_leaf(key, layer, i, lf.shape, lf.dtype,
+                                     lf.kind, lf.std)
+            for i, lf in enumerate(_leaves(m)[1]) if layer in lf.layers}
 
 
 def make_global(m: dict, seed: int) -> Dict[str, jax.Array]:
     key = run_key(seed)
-    return {path: _one_global_leaf(key, i, shape, dt, kind, std)
-            for i, (path, shape, dt, kind, std)
-            in enumerate(global_leaves(m))}
+    return {lf.path: _one_global_leaf(key, i, lf.shape, lf.dtype, lf.kind,
+                                      lf.std)
+            for i, lf in enumerate(_leaves(m)[0])}

@@ -97,7 +97,7 @@ def _attend(q, k, v, pos, seg):
 
 
 def _ffn(h, w, m, quant):
-    if not W.is_moe(m):
+    if not int(m.get("num_experts", 0)):
         g = _mm(h, w["mlp/w_gate"], quant)
         u = _mm(h, w["mlp/w_up"], quant)
         return _mm(jax.nn.silu(g) * u, w["mlp/w_down"], quant)

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 import tiny
 
+from arch import qwen3_moe
 from harness import check, costs, readings
 from harness.driver import ReqRecord
 from harness.readings import Context
@@ -75,7 +76,7 @@ def test_gap_stats():
 def test_paged_decode_cost_counts_live_tokens_only():
     m = {"num_attention_heads": 32, "num_key_value_heads": 4,
          "head_dim": 128}
-    ops, nbytes = costs.paged_decode_cost([100, 300], m)
+    ops, nbytes = qwen3_moe.paged_decode_cost([100, 300], m)
     assert ops == 4 * 32 * 128 * 400
     assert nbytes == 400 * 2 * 4 * 128 * 2 + 2 * 2 * 32 * 128 * 2
 
@@ -102,7 +103,7 @@ def test_model_flops():
     L, H, hd = 2, 4, 16
     assert f2 - f1 == pytest.approx(L * 4 * H * hd * 10)
     pf = costs.prefill_flops([(0, 4, True)], m)
-    per_tok = costs._per_token_layer_flops(m)
+    per_tok = qwen3_moe.token_flops(m, 0)
     attn = 4 * H * hd * (4 * 5 / 2)
     head = 2 * m["hidden_size"] * m["vocab_size"]
     assert pf == pytest.approx(L * (4 * per_tok + attn) + head)

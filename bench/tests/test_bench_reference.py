@@ -14,8 +14,8 @@ import numpy as np
 import pytest
 import tiny
 
+from arch.qwen3_moe import program_config
 from harness import weights as W
-from harness.cell import program_config
 from reference.qwen3 import served_logits
 
 

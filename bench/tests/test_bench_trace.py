@@ -52,3 +52,17 @@ def test_kernel_names():
     assert trace.kernel_of(decode) == "paged_decode"
     assert trace.kernel_of("%fusion.3 = f32[8]{0} fusion(f32[8]{0} %p)") \
         is None
+
+
+def test_named_custom_call_counts_under_its_own_name():
+    """A kernel that names its call is a kernel of that name; a call the
+    compiler names ``custom-call``, or that is no Pallas kernel, is none."""
+    named = ("%mla_decode.7 = bf16[32,32,512]{2,1,0} custom-call(bf16[32,"
+             "32,576]{2,1,0} %q), custom_call_target=\"tpu_custom_call\"")
+    bare = ("%custom-call.57 = bf16[4,2048,128]{2,1,0} custom-call(bf16[1,"
+            "2048,128]{2,1,0} %s), custom_call_target=\"tpu_custom_call\"")
+    buffer = ("%scratch.3 = s32[144]{0} custom-call(), "
+              "custom_call_target=\"AllocateBuffer\"")
+    assert trace.kernel_of(named) == "mla_decode"
+    assert trace.kernel_of(bare) is None
+    assert trace.kernel_of(buffer) is None

@@ -50,13 +50,15 @@ BACKLOG = {"kind": "backlog", "schedule_seed": 4, "n_requests": 6,
 
 def make_root(path: str, model=MODEL, mixes=None, extra_metrics=None):
     """A checkout root at ``path`` with one config, its mixes (one cell
-    each, named ``tiny.<mix>``) and the benchmark's metric readers."""
+    each, named ``tiny.<mix>``), the benchmark's metric readers and its
+    architecture modules."""
     mixes = mixes or {"chat": CHAT}
     bench = os.path.join(path, "bench")
     os.makedirs(os.path.join(bench, "configs"), exist_ok=True)
     os.makedirs(os.path.join(bench, "traffic"), exist_ok=True)
-    shutil.copytree(os.path.join(BENCH, "metrics"),
-                    os.path.join(bench, "metrics"), dirs_exist_ok=True)
+    for d in ("metrics", "arch"):
+        shutil.copytree(os.path.join(BENCH, d), os.path.join(bench, d),
+                        dirs_exist_ok=True)
     with open(os.path.join(bench, "configs", "tiny.json"), "w") as f:
         json.dump(model, f)
     for name, mix in mixes.items():
