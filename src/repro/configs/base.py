@@ -24,10 +24,62 @@ class MoEConfig:
     moe_every: int = 1           # every n-th layer is MoE (1 = all layers)
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
+    # router: "softmax" then top-k, or "sigmoid" scores (DeepSeek-V3) whose
+    # top-k is chosen by score + a per-expert correction bias when
+    # ``correction_bias`` (the bias chooses, it never weights); the chosen
+    # gates are renormalized, then multiplied by ``routed_scaling_factor``
+    scoring: str = "softmax"
+    correction_bias: bool = False
+    routed_scaling_factor: float = 1.0
+    n_group: int = 1             # group-limited selection: only 1 is served
+    topk_group: int = 1
+
+    def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router scoring {self.scoring!r}")
+        if self.correction_bias and self.scoring != "sigmoid":
+            raise ValueError("a correction bias needs sigmoid scoring")
+        if self.n_group > 1 or self.topk_group > 1:
+            raise NotImplementedError(
+                f"group-limited routing (n_group={self.n_group}, "
+                f"topk_group={self.topk_group}) is not implemented")
 
     @property
     def enabled(self) -> bool:
         return self.n_experts > 0
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): keys and values are
+    up-projected from a normed ``kv_lora_rank`` latent, which with one
+    roped ``qk_rope_head_dim`` key is all a token keeps in the cache.
+    RoPE turns (even, odd) pairs, as DeepSeek's ``rope_interleave``."""
+
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    q_lora_rank: int = 0         # low-rank queries: not implemented
+
+    def __post_init__(self):
+        if self.q_lora_rank:
+            raise NotImplementedError(
+                f"latent attention with q_lora_rank={self.q_lora_rank} "
+                "is not implemented (queries are projected in full)")
+
+    @property
+    def enabled(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Cached values per token per layer: latent, then roped key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -57,6 +109,10 @@ class ModelConfig:
 
     moe: MoEConfig = field(default_factory=MoEConfig)
     ssm: SSMConfig = field(default_factory=SSMConfig)
+    mla: MLAConfig = field(default_factory=MLAConfig)
+    # leading dense layers (DeepSeek's ``first_k_dense_replace``): SwiGLU of
+    # width ``d_ff`` before the repeating period; experts keep ``d_expert``
+    n_dense_lead: int = 0
 
     # attention variants
     rope_theta: float = 10000.0
@@ -97,9 +153,10 @@ class ModelConfig:
         return self.n_kv_heads * self.head_dim
 
     def is_moe_layer(self, layer_idx: int) -> bool:
-        if not self.moe.enabled:
+        if not self.moe.enabled or layer_idx < self.n_dense_lead:
             return False
-        return (layer_idx % self.moe.moe_every) == (self.moe.moe_every - 1)
+        j = layer_idx - self.n_dense_lead
+        return (j % self.moe.moe_every) == (self.moe.moe_every - 1)
 
     @property
     def n_moe_layers(self) -> int:
@@ -116,6 +173,11 @@ class ModelConfig:
         """Analytic parameter count (used for MODEL_FLOPS and memory math)."""
         d, hd = self.d_model, self.head_dim
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        if self.mla.enabled:
+            a, h = self.mla, self.n_heads
+            attn = (d * h * a.qk_head_dim + d * a.latent_dim + a.kv_lora_rank
+                    + a.kv_lora_rank * h * (a.qk_nope_head_dim + a.v_head_dim)
+                    + h * a.v_head_dim * d)
         if self.qkv_bias:
             attn += self.q_dim + 2 * self.kv_dim
         embed = self.vocab_size * d
@@ -149,6 +211,7 @@ class ModelConfig:
             if self.is_moe_layer(i):
                 m = self.moe
                 p += d * m.n_experts  # router
+                p += m.n_experts if m.correction_bias else 0
                 p += m.n_experts * 3 * d * m.d_expert
                 p += m.n_shared_experts * 3 * d * m.d_shared
             else:

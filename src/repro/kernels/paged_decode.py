@@ -44,49 +44,105 @@ def pages_per_block(page_size: int, n_slots: int) -> int:
     return max(1, min(n_slots, BLOCK_TOKENS // page_size))
 
 
+def next_live_lanes(ctx):
+    """The walk's ``nxt`` operand from ``ctx`` (lanes,): ``nxt[0]`` is the
+    first lane with a context, ``nxt[b + 1]`` the first such lane after b,
+    the lane count where there is none."""
+    B = ctx.shape[0]
+    lane = jnp.where(ctx > 0, jnp.arange(B, dtype=jnp.int32), B)
+    return jnp.append(jax.lax.cummin(lane, reverse=True), B).astype(jnp.int32)
+
+
+class LiveBlocks:
+    """One lane's walk over its live pages, a block of ``ppb`` block-table
+    slots at a time, with the copies of the next block in flight while one
+    is computed. Built inside a kernel whose grid is one step per lane and
+    whose scalar prefetch holds the block table ``bt_ref`` (lanes, slots),
+    ``ctx_ref`` (lanes,) and ``nxt_ref`` (:func:`next_live_lanes`).
+    ``page_copies(page, slot, i)`` gives the DMAs that bring page ``page``
+    into position ``i`` of double-buffer ``slot``; ``slot_ref`` (SMEM (1,))
+    holds the slot of the block in flight. The paged decode kernels share
+    it."""
+
+    def __init__(self, bt_ref, ctx_ref, nxt_ref, slot_ref, page_copies, *,
+                 ppb: int, ps: int):
+        self.b = pl.program_id(0)
+        self.n_lanes = pl.num_programs(0)
+        self.bt_ref, self.ctx_ref, self.nxt_ref = bt_ref, ctx_ref, nxt_ref
+        self.slot_ref, self.page_copies = slot_ref, page_copies
+        self.ppb, self.ps, self.bk = ppb, ps, ppb * ps   # bk: tokens a block
+
+    def _dmas(self, lane, blk, slot, i):
+        """The copies of page ``i`` of block ``blk`` into buffer ``slot``."""
+        return self.page_copies(self.bt_ref[lane, blk * self.ppb + i], slot,
+                                i)
+
+    def _live_pages(self, lane, blk):   # pages past the context: never read
+        return jnp.minimum(self.ppb, pl.cdiv(
+            self.ctx_ref[lane] - blk * self.bk, self.ps))
+
+    def _start(self, lane, blk, slot):
+        @pl.loop(0, self._live_pages(lane, blk))
+        def _(i):
+            for c in self._dmas(lane, blk, slot, i):
+                c.start()
+
+    def _wait(self, lane, blk, slot):
+        @pl.loop(0, self._live_pages(lane, blk))
+        def _(i):
+            for c in self._dmas(lane, blk, slot, i):
+                c.wait()
+
+    def begin(self):
+        """Start this lane's walk: the first live lane fetches its own first
+        block; later lanes find theirs in flight, started by the lane
+        before. Returns (context, blocks, next live lane or ``n_lanes``)."""
+        b = self.b
+
+        @pl.when(b == self.nxt_ref[0])
+        def _first():
+            self.slot_ref[0] = 0
+            self._start(b, 0, 0)
+
+        ctx = self.ctx_ref[b]
+        return ctx, pl.cdiv(ctx, self.bk), self.nxt_ref[b + 1]
+
+    def fetch(self, i, n_blocks, next_lane):
+        """Start the copies of the block after ``i`` (or of the next live
+        lane's first block), wait for block ``i``'s, and return the buffer
+        slot that holds it."""
+        slot = self.slot_ref[0]
+
+        @pl.when(i + 1 < n_blocks)
+        def _():
+            self._start(self.b, i + 1, 1 - slot)
+
+        @pl.when((i + 1 == n_blocks) & (next_lane < self.n_lanes))
+        def _():
+            self._start(next_lane, 0, 1 - slot)
+
+        self.slot_ref[0] = 1 - slot
+        self._wait(self.b, i, slot)
+        return slot
+
+
 def _kernel(bt_ref, ctx_ref, nxt_ref, q_ref, k_hbm, v_hbm, *rest,
             scale, ppb, quant):
     if quant:
         ks_ref, vs_ref, o_ref, k_buf, v_buf, sems, slot_ref = rest
     else:
         o_ref, k_buf, v_buf, sems, slot_ref = rest
-    b = pl.program_id(0)
-    n_lanes = pl.num_programs(0)
     _, _, ps, Hkv, hd = k_buf.shape
-    bk = ppb * ps                                 # tokens per block
-
-    def dmas(lane, blk, slot, i):
-        """The K and V copies of page ``i`` of block ``blk`` into buffer
-        ``slot``."""
-        page = bt_ref[lane, blk * ppb + i]
-        return [pltpu.make_async_copy(src.at[page], dst.at[slot, i],
-                                      sems.at[kv, slot])
-                for kv, (src, dst) in enumerate([(k_hbm, k_buf),
-                                                 (v_hbm, v_buf)])]
-
-    def live_pages(lane, blk):          # pages past the context: never read
-        return jnp.minimum(ppb, pl.cdiv(ctx_ref[lane] - blk * bk, ps))
-
-    def start(lane, blk, slot):
-        @pl.loop(0, live_pages(lane, blk))
-        def _(i):
-            for c in dmas(lane, blk, slot, i):
-                c.start()
-
-    def wait(lane, blk, slot):
-        @pl.loop(0, live_pages(lane, blk))
-        def _(i):
-            for c in dmas(lane, blk, slot, i):
-                c.wait()
-
-    @pl.when(b == nxt_ref[0])         # the first live lane fetches its own
-    def _first():                     # first block; later lanes find theirs
-        slot_ref[0] = 0               # in flight (started by the lane before)
-        start(b, 0, 0)
-
-    ctx = ctx_ref[b]
-    n_blocks = pl.cdiv(ctx, bk)
-    next_lane = nxt_ref[b + 1]        # first live lane after b (or n_lanes)
+    walk = LiveBlocks(
+        bt_ref, ctx_ref, nxt_ref, slot_ref,
+        lambda page, slot, i: [
+            pltpu.make_async_copy(src.at[page], dst.at[slot, i],
+                                  sems.at[kv, slot])
+            for kv, (src, dst) in enumerate([(k_hbm, k_buf),
+                                             (v_hbm, v_buf)])],
+        ppb=ppb, ps=ps)
+    bk = walk.bk
+    ctx, n_blocks, next_lane = walk.begin()
     Hq = q_ref.shape[1]
     G = Hq // Hkv
     q = q_ref[0]                                  # (Hq, hd)
@@ -95,18 +151,7 @@ def _kernel(bt_ref, ctx_ref, nxt_ref, q_ref, k_hbm, v_hbm, *rest,
 
     def block(i, carry):
         m_prev, l_prev, acc = carry     # (Hkv, G), (Hkv, G), (Hkv, G, hd)
-        slot = slot_ref[0]
-
-        @pl.when(i + 1 < n_blocks)
-        def _():
-            start(b, i + 1, 1 - slot)
-
-        @pl.when((i + 1 == n_blocks) & (next_lane < n_lanes))
-        def _():
-            start(next_lane, 0, 1 - slot)
-
-        slot_ref[0] = 1 - slot
-        wait(b, i, slot)
+        slot = walk.fetch(i, n_blocks, next_lane)
 
         # slots past the context were not fetched (stale VMEM): select, never
         # multiply, them away
@@ -162,9 +207,7 @@ def paged_decode_pallas(q, k_pages, v_pages, block_tables, ctx_lens, *,
     pad = -NB % ppb
     bt = jnp.pad(block_tables.astype(jnp.int32), ((0, 0), (0, pad)))
     ctx = jnp.minimum(ctx_lens.astype(jnp.int32), NB * ps)
-    # nxt[0]: first lane with a context; nxt[b + 1]: first such lane after b
-    lane = jnp.where(ctx > 0, jnp.arange(B, dtype=jnp.int32), B)
-    nxt = jnp.append(jax.lax.cummin(lane, reverse=True), B).astype(jnp.int32)
+    nxt = next_live_lanes(ctx)
     quant = k_scales is not None
 
     kernel = functools.partial(_kernel, scale=1.0 / np.sqrt(hd), ppb=ppb,

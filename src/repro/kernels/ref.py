@@ -74,3 +74,22 @@ def flash_decode_ref(q, k_cache, v_cache, k_pos, q_pos):
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgl,blkd->bkgd", p, v)
     return out.reshape(B, Hq, hd).astype(q.dtype)
+
+
+def mla_decode_ref(q, pages, block_tables, ctx_lens, *, rank: int,
+                   scale: float):
+    """Latent decode oracle, one lane at a time: softmax over the lane's
+    first ``ctx_lens`` rows of scores ``q . row * scale``, weighting the
+    rows' first ``rank`` values. Lanes with ctx_lens == 0 -> 0."""
+    _, ps, _ = pages.shape
+    out = []
+    for b in range(q.shape[0]):
+        n = int(ctx_lens[b])
+        if n == 0:
+            out.append(jnp.zeros((q.shape[1], rank), jnp.float32))
+            continue
+        rows = pages[block_tables[b]].reshape(-1, pages.shape[-1])[:n]
+        rows = rows.astype(jnp.float32)
+        p = jax.nn.softmax(q[b].astype(jnp.float32) @ rows.T * scale, -1)
+        out.append(p @ rows[:, :rank])
+    return jnp.stack(out).astype(q.dtype)

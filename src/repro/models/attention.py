@@ -66,7 +66,8 @@ def flash_attention(q, k, v, *, q_pos, k_pos, causal: bool = True,
                     q_block: int = 512, kv_block: int = 512,
                     scale: Optional[float] = None,
                     k_scale=None, v_scale=None):
-    """q: (B, Sq, Hq, hd), k/v: (B, Skv, Hkv, hd) -> (B, Sq, Hq, hd).
+    """q, k: (B, Sq | Skv, Hq | Hkv, hd), v: (B, Skv, Hkv, hd_v) ->
+    (B, Sq, Hq, hd_v); ``scale`` defaults to ``hd ** -0.5``.
 
     q_pos: (B, Sq) int32 absolute positions; k_pos: (B, Skv) int32 absolute
     positions with -1 marking invalid slots. ``window`` may be a traced scalar
@@ -75,6 +76,7 @@ def flash_attention(q, k, v, *, q_pos, k_pos, causal: bool = True,
     """
     B, Sq, Hq, hd = q.shape
     _, Skv, Hkv, _ = k.shape
+    hd_v = v.shape[-1]
     G = Hq // Hkv
     if scale is None:
         scale = 1.0 / np.sqrt(hd)
@@ -93,7 +95,7 @@ def flash_attention(q, k, v, *, q_pos, k_pos, causal: bool = True,
             k_scale = jnp.pad(k_scale, ((0, 0), (0, pad_kv), (0, 0)))
             v_scale = jnp.pad(v_scale, ((0, 0), (0, pad_kv), (0, 0)))
     kb = k.reshape(B, n_kv, kv_block, Hkv, hd).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(B, n_kv, kv_block, Hkv, hd).transpose(1, 0, 2, 3, 4)
+    vb = v.reshape(B, n_kv, kv_block, Hkv, hd_v).transpose(1, 0, 2, 3, 4)
     kpb = k_pos.reshape(B, n_kv, kv_block).transpose(1, 0, 2)
     quant = k_scale is not None
     if quant:
@@ -122,7 +124,7 @@ def flash_attention(q, k, v, *, q_pos, k_pos, causal: bool = True,
         Bq = qcur.shape[1]
         m0 = jnp.full((B, Hkv, G, Bq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, Hkv, G, Bq), jnp.float32)
-        a0 = jnp.zeros((B, Hkv, G, Bq, hd), jnp.float32)
+        a0 = jnp.zeros((B, Hkv, G, Bq, hd_v), jnp.float32)
 
         def step(carry, xs):
             kblk, vblk, kpos_blk, ks_blk, vs_blk = xs
@@ -136,7 +138,7 @@ def flash_attention(q, k, v, *, q_pos, k_pos, causal: bool = True,
 
     if Sq <= q_block:
         out = attend_q_block(qg, q_pos)
-        return out.reshape(B, Sq, Hq, hd)
+        return out.reshape(B, Sq, Hq, hd_v)
 
     # ---- outer scan over q blocks
     n_q = -(-Sq // q_block)
@@ -152,7 +154,8 @@ def flash_attention(q, k, v, *, q_pos, k_pos, causal: bool = True,
         return None, attend_q_block(qcur, qpos_cur)
 
     _, outs = jax.lax.scan(q_step, None, (qblocks, qpos_blocks))
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, n_q * q_block, Hq, hd)
+    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, n_q * q_block, Hq,
+                                                   hd_v)
     return out[:, :Sq]
 
 

@@ -60,6 +60,16 @@ def apply_rope(x, positions, theta: float):
     return out.astype(x.dtype)
 
 
+def apply_rope_interleaved(x, positions, theta: float):
+    """RoPE over (even, odd) pairs (DeepSeek's ``rope_interleave``): the
+    pairs are gathered into halves, evens first, and rotated as
+    :func:`apply_rope` rotates halves. The result stays in that order, as
+    Hugging Face's ``apply_rotary_pos_emb_interleave`` leaves it."""
+    hd = x.shape[-1]
+    halves = x.reshape(*x.shape[:-1], hd // 2, 2).swapaxes(-1, -2)
+    return apply_rope(halves.reshape(x.shape), positions, theta)
+
+
 # ---------------------------------------------------------------- MLP
 def init_mlp(key, d_model: int, d_ff: int, dtype=jnp.bfloat16):
     k1, k2, k3 = jax.random.split(key, 3)

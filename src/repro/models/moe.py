@@ -57,17 +57,36 @@ def init_moe(key, cfg, d_model: Optional[int] = None):
     if m.n_shared_experts:
         from repro.models.layers import init_mlp
         p["shared"] = init_mlp(ks[4], d, m.n_shared_experts * m.d_shared, dt)
+    if m.correction_bias:
+        p["router_bias"] = jnp.zeros((m.n_experts,), jnp.float32)
     return p
 
 
 def route(params, cfg, x2d):
-    """Router: x2d (..., D) -> (gates (..., K), ids (..., K), probs (..., E))."""
+    """Router: x2d (..., D) -> (gates (..., K), ids (..., K), probs (..., E)).
+
+    Softmax scoring takes the top-k probabilities. Sigmoid scoring
+    (DeepSeek-V3's ``noaux_tc`` with one group) takes the top-k of the
+    scores plus ``router_bias`` where the config has one, and gates by the
+    unbiased scores. Either way the gates are renormalized over the chosen
+    experts, then scaled by ``routed_scaling_factor``."""
+    m = cfg.moe
     logits = jnp.einsum("...d,de->...e", x2d.astype(jnp.float32),
                         params["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, cfg.moe.top_k)
+    if m.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores + params["router_bias"] if m.correction_bias \
+            else scores
+        _, expert_idx = jax.lax.top_k(choice, m.top_k)
+        gate_vals = jnp.take_along_axis(scores, expert_idx, axis=-1)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, expert_idx = jax.lax.top_k(probs, m.top_k)
     gates = gate_vals / jnp.maximum(
         jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    if m.routed_scaling_factor != 1.0:
+        gates = gates * m.routed_scaling_factor
     return gates, expert_idx, probs
 
 

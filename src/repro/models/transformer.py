@@ -10,6 +10,16 @@ Three entry points per model: ``loss`` (train), ``prefill`` (S tokens, builds
 KV cache, emits Gimbal MoE statistics), ``decode`` (1 token against the
 cache). MoE layers take the Gimbal expert ``placement`` (n_moe_layers, E) as a
 runtime input and emit per-layer A[s,e] / B[e] statistics as outputs.
+
+Leading dense layers (``cfg.n_dense_lead``, DeepSeek's
+``first_k_dense_replace``) sit in a stack of their own, ``params["lead"]``
+with its own pool entry ``pages["lead"]``, and run one by one before the
+scan; placement rows, statistics and expert migration cover the scanned
+MoE layers only. Latent attention (``cfg.mla``) keeps one latent row per
+token in the paged pool and has two paths: chunked prefill up-projects the
+gathered latents into per-head keys and values; decode folds the key
+up-projection into the query and attends over the latents
+(``kernels/mla_decode``).
 """
 from __future__ import annotations
 
@@ -25,9 +35,10 @@ from repro.configs.base import ModelConfig
 from repro.models import moe as moe_mod
 from repro.models.attention import (decode_attention, flash_attention,
                                     ring_positions)
-from repro.models.layers import (apply_rope, cross_entropy, dense_init,
-                                 embed_tokens, init_embed, init_mlp,
-                                 lm_logits, mlp, rms_norm)
+from repro.models.layers import (apply_rope, apply_rope_interleaved,
+                                 cross_entropy, dense_init, embed_tokens,
+                                 init_embed, init_mlp, lm_logits, mlp,
+                                 rms_norm)
 from repro.models.ssm import init_mamba, mamba_block, mamba_state_init
 
 
@@ -43,24 +54,33 @@ def _lcm(a: int, b: int) -> int:
 
 
 def period_descriptors(cfg: ModelConfig) -> List[LayerDesc]:
+    """The repeating period of the layers after the leading dense ones."""
     moe_p = cfg.moe.moe_every if cfg.moe.enabled else 1
     loc_p = (cfg.local_global_ratio + 1) if cfg.local_global_ratio > 0 else 1
     P = _lcm(moe_p, loc_p)
-    if cfg.n_layers % P:
+    lead = cfg.n_dense_lead
+    if (cfg.n_layers - lead) % P:
         raise ValueError(
-            f"{cfg.name}: n_layers={cfg.n_layers} not divisible by period {P}")
+            f"{cfg.name}: n_layers={cfg.n_layers} less {lead} leading dense "
+            f"not divisible by period {P}")
+    if lead and (cfg.family == "hybrid" or loc_p > 1):
+        raise NotImplementedError("leading dense layers in a hybrid or "
+                                  "windowed stack")
     descs = []
     for j in range(P):
         descs.append(LayerDesc(
             local=cfg.is_local_layer(j),
-            moe=cfg.is_moe_layer(j),
+            moe=cfg.is_moe_layer(lead + j),
             hybrid=(cfg.family == "hybrid"),
         ))
     return descs
 
 
 def n_super_blocks(cfg: ModelConfig) -> int:
-    return cfg.n_layers // len(period_descriptors(cfg))
+    return (cfg.n_layers - cfg.n_dense_lead) // len(period_descriptors(cfg))
+
+
+LEAD = LayerDesc()                     # a leading dense layer
 
 
 # ------------------------------------------------------------------ init
@@ -84,11 +104,30 @@ def init_attention(key, cfg: ModelConfig):
     return p
 
 
+def init_mla(key, cfg: ModelConfig):
+    """Latent attention without low-rank queries: ``wq`` (D -> H x (nope +
+    rope)), ``wkv_a`` (D -> rank + rope), an RMSNorm on the latent,
+    ``wkv_b`` (rank -> H x (nope + v)) and ``wo`` (H x v -> D)."""
+    a, H, d = cfg.mla, cfg.n_heads, cfg.d_model
+    ks = jax.random.split(key, 4)
+    dt = jnp.dtype(cfg.dtype)
+    return {
+        "wq": dense_init(ks[0], (d, H * a.qk_head_dim), 0, dt),
+        "wkv_a": dense_init(ks[1], (d, a.latent_dim), 0, dt),
+        "kv_norm": jnp.zeros((a.kv_lora_rank,), jnp.float32),
+        "wkv_b": dense_init(ks[2], (a.kv_lora_rank,
+                                    H * (a.qk_nope_head_dim + a.v_head_dim)),
+                            0, dt),
+        "wo": dense_init(ks[3], (H * a.v_head_dim, d), 0, dt),
+    }
+
+
 def init_layer(key, cfg: ModelConfig, desc: LayerDesc):
     ks = jax.random.split(key, 4)
     p: Dict[str, Any] = {
         "ln_attn": jnp.zeros((cfg.d_model,), jnp.float32),
-        "attn": init_attention(ks[0], cfg),
+        "attn": (init_mla(ks[0], cfg) if cfg.mla.enabled
+                 else init_attention(ks[0], cfg)),
         "ln_ffn": jnp.zeros((cfg.d_model,), jnp.float32),
     }
     if desc.moe:
@@ -116,11 +155,16 @@ def init_params(key, cfg: ModelConfig):
         keys = jax.random.split(jax.random.fold_in(k_blocks, j), ns)
         stacked = jax.vmap(lambda k: init_layer(k, cfg, desc))(keys)
         blocks[f"pos{j}"] = stacked
-    return {
+    params = {
         "embed": init_embed(k_embed, cfg),
         "blocks": blocks,
         "final_norm": jnp.zeros((cfg.d_model,), jnp.float32),
     }
+    if cfg.n_dense_lead:
+        keys = jax.random.split(jax.random.fold_in(key, 1 << 20),
+                                cfg.n_dense_lead)
+        params["lead"] = jax.vmap(lambda k: init_layer(k, cfg, LEAD))(keys)
+    return params
 
 
 # ------------------------------------------------------------------ cache
@@ -134,6 +178,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                kv_dtype: str = "bfloat16"):
     """kv_dtype='int8' stores quantized KV + per-(token, head) scales —
     needed to fit e.g. the MHA 32k x 128 decode cell in 16 GB/chip."""
+    if cfg.mla.enabled or cfg.n_dense_lead:
+        raise NotImplementedError("latent attention and leading dense "
+                                  "layers are served from the paged pool "
+                                  "(init_paged_cache)")
     descs = period_descriptors(cfg)
     ns = n_super_blocks(cfg)
     quant = kv_dtype == "int8"
@@ -172,6 +220,11 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
     scale arrays (kernels/kv_pack.py) — the same pool bytes hold roughly
     ``2*hd/(hd+4)`` times the tokens of the fp layout; attention reads
     dequantize on the fly. ``"auto"`` keeps the model dtype (bit-exact).
+
+    Latent attention keeps ``{"latent": (n_super, n_pages, page_size,
+    Wp)}`` instead: a token's normed latent and roped key, zero-padded to
+    whole 128-lane tiles (``kernels/mla_decode.padded_width``). Leading
+    dense layers get the entry ``"lead"``, stacked over those layers.
     """
     if cfg.family == "hybrid":
         raise NotImplementedError("paged KV: mamba state is not paged")
@@ -183,19 +236,29 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
         # config would make decode diverge from the softcapped prefill
         raise NotImplementedError("paged KV: attn_logit_softcap "
                                   "unsupported in paged_decode")
-    descs = period_descriptors(cfg)
-    ns = n_super_blocks(cfg)
     quant = kv_dtype == "int8"
+    if quant and cfg.mla.enabled:
+        raise NotImplementedError("int8 pages of latent attention are not "
+                                  "implemented")
     dt = jnp.int8 if quant else jnp.dtype(cfg.dtype)
-    shape = (ns, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-    out = {}
-    for j in range(len(descs)):
+
+    def entry(ns):
+        if cfg.mla.enabled:
+            from repro.kernels.mla_decode import padded_width
+            width = padded_width(cfg.mla.latent_dim)
+            return {"latent": jnp.zeros((ns, n_pages, page_size, width), dt)}
+        shape = (ns, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
         c = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
         if quant:
             sshape = (ns, n_pages, page_size, cfg.n_kv_heads)
             c["k_scale"] = jnp.zeros(sshape, jnp.float32)
             c["v_scale"] = jnp.zeros(sshape, jnp.float32)
-        out[f"pos{j}"] = c
+        return c
+
+    ns = n_super_blocks(cfg)
+    out = {f"pos{j}": entry(ns) for j in range(len(period_descriptors(cfg)))}
+    if cfg.n_dense_lead:
+        out["lead"] = entry(cfg.n_dense_lead)
     return out
 
 
@@ -265,6 +328,17 @@ def _qkv(lp, cfg, xn, positions):
     return q, k, v
 
 
+def _pool_slots(bt, valid, positions, page_size):
+    """Page and offset in the pool of each row a call writes, flattened;
+    rows not ``valid`` (chunk padding, inactive lanes) go to garbage page
+    0. bt (B, NB) int32; valid, positions (B, S)."""
+    bidx = jnp.arange(bt.shape[0])[:, None]
+    blk = jnp.clip(positions // page_size, 0, bt.shape[1] - 1)
+    page = jnp.where(valid, bt[bidx, blk], 0).reshape(-1)
+    off = jnp.where(valid, positions % page_size, 0).reshape(-1)
+    return page, off
+
+
 def _paged_attention(cfg, q, k, v, positions, cache, mode, paged):
     """Paged-KV attention: scatter the new k/v into physical pages via the
     block table, then attend through the block table.
@@ -288,12 +362,8 @@ def _paged_attention(cfg, q, k, v, positions, cache, mode, paged):
     ps = pk.shape[1]
     bt = paged["block_tables"].astype(jnp.int32)       # (B, NB)
     NB = bt.shape[1]
-    valid = paged["valid"]                             # (B, S)
     ctx = paged["ctx_lens"].astype(jnp.int32)          # (B,)
-    bidx = jnp.arange(B)[:, None]
-    blk = jnp.clip(positions // ps, 0, NB - 1)
-    page = jnp.where(valid, bt[bidx, blk], 0).reshape(-1)
-    off = jnp.where(valid, positions % ps, 0).reshape(-1)
+    page, off = _pool_slots(bt, paged["valid"], positions, ps)
     Hkv, hd = pk.shape[2], pk.shape[3]
     quant = "k_scale" in cache
     backend = paged.get("backend", "auto")
@@ -429,6 +499,95 @@ def attention_block(lp, cfg, desc, x, positions, cache, mode, policy=None,
     return out, new_cache
 
 
+def _mla_project(lp, cfg, xn, positions):
+    """xn (B, S, D) -> (q (B, S, H, nope + rope), latent rows (B, S, W)):
+    a row is the normed latent, then the roped key shared by the heads."""
+    a = cfg.mla
+    B, S, _ = xn.shape
+    nope, r = a.qk_nope_head_dim, a.kv_lora_rank
+    q = jnp.einsum("bsd,df->bsf", xn, lp["wq"]).reshape(
+        B, S, cfg.n_heads, a.qk_head_dim)
+    q_pe = apply_rope_interleaved(q[..., nope:], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    kv = jnp.einsum("bsd,df->bsf", xn, lp["wkv_a"])
+    c = rms_norm(kv[..., :r], lp["kv_norm"], cfg.norm_eps)
+    k_pe = apply_rope_interleaved(kv[..., None, r:], positions,
+                                  cfg.rope_theta)[..., 0, :]
+    return q, jnp.concatenate([c, k_pe], axis=-1)
+
+
+def _mla_up(lp, cfg, rows):
+    """Non-absorbed keys and values of latent rows (B, L, >= W):
+    (k (B, L, H, nope + rope), v (B, L, H, v))."""
+    a = cfg.mla
+    nope, r, H = a.qk_nope_head_dim, a.kv_lora_rank, cfg.n_heads
+    B, L = rows.shape[:2]
+    kv = jnp.einsum("blr,rf->blf", rows[..., :r], lp["wkv_b"]).reshape(
+        B, L, H, nope + a.v_head_dim)
+    k_pe = jnp.broadcast_to(rows[..., None, r:a.latent_dim],
+                            (B, L, H, a.qk_rope_head_dim))
+    return jnp.concatenate([kv[..., :nope], k_pe], axis=-1), kv[..., nope:]
+
+
+def _paged_mla(lp, cfg, q, rows, positions, cache, mode, paged):
+    """Latent attention over the paged pool: write this call's rows, then
+    attend. ``cache``: {"latent": (P, ps, Wp)}; ``paged`` as in
+    :func:`_paged_attention`. Decode (S == 1) is the absorbed form in
+    ``kernels/mla_decode``; chunked prefill gathers the request's rows
+    through the block table and up-projects them (dense gather over the
+    whole table, as the GQA path does)."""
+    from repro.kernels.mla_decode import mla_decode
+    if paged.get("decode_mask") is not None:
+        raise NotImplementedError("mixed steps with latent attention")
+    a = cfg.mla
+    nope, r, H = a.qk_nope_head_dim, a.kv_lora_rank, cfg.n_heads
+    B, S = positions.shape
+    pool = cache["latent"]
+    ps, Wp = pool.shape[1], pool.shape[2]
+    bt = paged["block_tables"].astype(jnp.int32)       # (B, NB)
+    NB = bt.shape[1]
+    ctx = paged["ctx_lens"].astype(jnp.int32)
+    page, off = _pool_slots(bt, paged["valid"], positions, ps)
+    pad = ((0, 0), (0, 0), (0, Wp - a.latent_dim))
+    pool = pool.at[page, off].set(
+        jnp.pad(rows, pad).reshape(B * S, Wp).astype(pool.dtype))
+    new_cache = dict(cache, latent=pool)
+    scale = 1.0 / np.sqrt(a.qk_head_dim)
+    if mode == "paged_decode":                         # S == 1, absorbed
+        w = lp["wkv_b"].reshape(r, H, nope + a.v_head_dim)
+        q_lat = jnp.einsum("bhn,rhn->bhr", q[:, 0, :, :nope], w[..., :nope],
+                           preferred_element_type=jnp.float32)
+        qa = jnp.concatenate([q_lat.astype(q.dtype), q[:, 0, :, nope:]], -1)
+        qa = jnp.pad(qa, pad)
+        o = mla_decode(qa, pool, bt, ctx, rank=r, scale=scale,
+                       backend=paged.get("backend", "auto"),
+                       interpret=paged.get("interpret", False))
+        out = jnp.einsum("bhr,rhv->bhv", o, w[..., nope:])
+        return out[:, None], new_cache
+    L = NB * ps
+    k, v = _mla_up(lp, cfg, pool[bt].reshape(B, L, Wp))
+    kpos = jnp.arange(L, dtype=jnp.int32)[None]
+    kpos = jnp.where(kpos < ctx[:, None], kpos, -1)
+    out = flash_attention(q, k, v, q_pos=positions, k_pos=kpos, causal=True,
+                          window=0, scale=scale)
+    return out, new_cache
+
+
+def mla_attention_block(lp, cfg, x, positions, cache, mode, paged=None):
+    """Latent attention (DeepSeek-V3, no low-rank queries) through the
+    paged latent pool. x: (B, S, D)."""
+    if mode not in ("paged_prefill", "paged_decode"):
+        raise NotImplementedError("latent attention is served from the "
+                                  "paged pool")
+    B, S, _ = x.shape
+    xn = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
+    q, rows = _mla_project(lp["attn"], cfg, xn, positions)
+    out, cache = _paged_mla(lp["attn"], cfg, q, rows, positions, cache,
+                            mode, paged)
+    out = out.reshape(B, S, cfg.n_heads * cfg.mla.v_head_dim)
+    return jnp.einsum("bsf,fd->bsd", out, lp["attn"]["wo"]), cache
+
+
 def _split_decode(q, ck, cv, positions, k_pos, window, cap, n_split,
                   k_scale=None, v_scale=None):
     """Flash-decode with KV split across ``n_split`` shards (split-K SP)."""
@@ -476,8 +635,15 @@ def decoder_layer(lp, cfg, desc, x, positions, cache, mode, placement_row,
                   source_ids, n_sources, policy=None, collect_stats=True,
                   paged=None):
     """Returns (x, new_cache, stats_or_None)."""
-    attn_out, new_cache = attention_block(lp, cfg, desc, x, positions, cache,
-                                          mode, policy, paged)
+    if cfg.mla.enabled:
+        if policy is not None:
+            raise NotImplementedError("latent attention under a sharding "
+                                      "policy")
+        attn_out, new_cache = mla_attention_block(lp, cfg, x, positions,
+                                                  cache, mode, paged)
+    else:
+        attn_out, new_cache = attention_block(lp, cfg, desc, x, positions,
+                                              cache, mode, policy, paged)
     if desc.hybrid:
         xn = rms_norm(x, lp["ln_attn"], cfg.norm_eps)
         state = None
@@ -641,6 +807,45 @@ def _stack_forward(params, cfg, x, positions, cache, mode, placement,
     return x, new_cache, stats
 
 
+def _lead_forward(params, cfg, x, positions, cache, mode, policy,
+                  paged=None):
+    """The leading dense layers, one at a time, before the scan. Their
+    stack ``params["lead"]`` (and pool entry) is indexed by layer; at one
+    layer the index is a reshape, not a copy."""
+    new = []
+    for i in range(cfg.n_dense_lead):
+        at = lambda a, i=i: a[i]
+        c = None if cache is None else jax.tree.map(at, cache)
+        x, nc, _ = decoder_layer(jax.tree.map(at, params["lead"]), cfg, LEAD,
+                                 x, positions, c, mode, None, None, 0,
+                                 policy, False, paged)
+        new.append(nc)
+    if cache is None:
+        return x, None
+    return x, jax.tree.map(lambda *a: jnp.stack(a), *new)
+
+
+def _forward(params, cfg, x, positions, cache, mode, placement, source_ids,
+             n_sources, policy, collect_stats, remat, paged=None):
+    """The leading dense layers (if any), then the scan over super-blocks.
+    Returns (x, new_cache, stats of the scanned MoE layers)."""
+    if not cfg.n_dense_lead:
+        return _stack_forward(params, cfg, x, positions, cache, mode,
+                              placement, source_ids, n_sources, policy,
+                              collect_stats, remat, paged)
+    blk_cache = lead_cache = None
+    if cache is not None:
+        blk_cache = {k: v for k, v in cache.items() if k != "lead"}
+        lead_cache = cache["lead"]
+    x, lead_cache = _lead_forward(params, cfg, x, positions, lead_cache,
+                                  mode, policy, paged)
+    x, blk_cache, stats = _stack_forward(
+        params, cfg, x, positions, blk_cache, mode, placement, source_ids,
+        n_sources, policy, collect_stats, remat, paged)
+    new_cache = None if cache is None else dict(blk_cache, lead=lead_cache)
+    return x, new_cache, stats
+
+
 def _inputs_to_embed(params, cfg, batch):
     if cfg.input_mode == "embeddings" and "embeddings" in batch:
         return batch["embeddings"]
@@ -657,7 +862,7 @@ def loss_fn(params, cfg: ModelConfig, batch, *, placement=None,
         x = policy.shard_resid(x)
     if placement is None:
         placement = identity_placement(cfg)
-    x, _, stats = _stack_forward(
+    x, _, stats = _forward(
         params, cfg, x, positions, None, "train", placement, None, 0,
         policy, collect_stats=cfg.moe.enabled, remat=True)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -682,7 +887,7 @@ def prefill(params, cfg: ModelConfig, batch, cache, *, placement=None,
         x = policy.shard_resid(x)
     if placement is None:
         placement = identity_placement(cfg)
-    x, cache, stats = _stack_forward(
+    x, cache, stats = _forward(
         params, cfg, x, positions, cache, "prefill", placement, source_ids,
         n_sources, policy, collect_stats, remat=False)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -721,7 +926,7 @@ def prefill_chunk_paged(params, cfg: ModelConfig, batch, pages, *,
              "backend": attn_backend, "interpret": interpret}
     if placement is None:
         placement = identity_placement(cfg)
-    x, pages, stats = _stack_forward(
+    x, pages, stats = _forward(
         params, cfg, x, positions, pages, "paged_prefill", placement,
         source_ids, n_sources, None, collect_stats, remat=False, paged=paged)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -786,7 +991,7 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, pages, lengths, *,
              "backend": attn_backend, "interpret": interpret}
     if placement is None:
         placement = identity_placement(cfg)
-    x, pages, stats = _stack_forward(
+    x, pages, stats = _forward(
         params, cfg, x, positions, pages, "paged_decode", placement,
         source_ids, n_sources, None, collect_stats, remat=False, paged=paged)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -802,7 +1007,7 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, lengths, *,
     positions = lengths[:, None].astype(jnp.int32)
     if placement is None:
         placement = identity_placement(cfg)
-    x, cache, stats = _stack_forward(
+    x, cache, stats = _forward(
         params, cfg, x, positions, cache, "decode", placement, source_ids,
         n_sources, policy, collect_stats, remat=False)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -286,6 +286,10 @@ class PagedRealEngine:
             "engine fuses more prefill lanes than the runner's lane buckets"
         assert self.ecfg.kv_dtype == self.runner.ecfg.kv_dtype, \
             "engine/runner kv_dtype mismatch"
+        if self.ecfg.mixed_steps and cfg.mla.enabled:
+            raise NotImplementedError("mixed steps with latent attention: "
+                                      "its decode rows are not fused into "
+                                      "prefill calls")
         self.sharing = self.ecfg.prefix_sharing
         self.tier = tier
         self.pool = self._make_pool()
@@ -618,7 +622,8 @@ class PagedRealEngine:
         n_tokens = sum(l.chunk for l in group)
         t0 = time.perf_counter()
         with span(spans.LAUNCH + "prefill", lanes=len(group),
-                  tokens=n_tokens, engine=self.engine_id):
+                  tokens=n_tokens, ctx=int(starts.sum() + lens.sum()),
+                  engine=self.engine_id):
             logits, self.pages, stats = self.runner.prefill_chunk(
                 batch, self.pages, bt, placement, source_ids)
         with span(spans.READBACK, kind="prefill"):
@@ -696,7 +701,8 @@ class PagedRealEngine:
             source_ids = jnp.full((B,), self.engine_id, jnp.int32)
         t0 = time.perf_counter()
         with span(spans.LAUNCH + "mixed", lanes=len(group),
-                  tokens=int(lens.sum()), engine=self.engine_id):
+                  tokens=int(lens.sum()), ctx=int(starts.sum() + lens.sum()),
+                  engine=self.engine_id):
             logits, self.pages, stats = self.runner.mixed_step(
                 batch, self.pages, bt, placement, source_ids)
         with span(spans.READBACK, kind="mixed"):
@@ -767,13 +773,14 @@ class PagedRealEngine:
                 rids[i] = r.req_id
             bt = self.pool.block_table_array(rids,
                                              self.ecfg.max_blocks_per_req)
+            n_ctx = int(lengths.sum()) + len(lanes)
             tokens, lengths, bt, active = (
                 jnp.asarray(a) for a in (tokens, lengths, bt, active))
             placement = jnp.asarray(self.placement)
             source_ids = jnp.full((B,), self.engine_id, jnp.int32)
         t0 = time.perf_counter()
         with span(spans.LAUNCH + "decode", lanes=len(lanes),
-                  tokens=len(lanes), engine=self.engine_id):
+                  tokens=len(lanes), ctx=n_ctx, engine=self.engine_id):
             logits, self.pages, stats = self.runner.decode(
                 tokens, self.pages, lengths, bt, active, placement,
                 source_ids)

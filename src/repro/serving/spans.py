@@ -13,7 +13,8 @@ Spans nest on the serving thread, each inside the one that caused it::
       gimbal/engine_step          PagedRealEngine.step
         gimbal/plan               StepPlanner.plan
         gimbal/inputs             host arrays, block table, host-to-device
-        gimbal/launch/<kind>      the step program's (async) enqueue
+        gimbal/launch/<kind>      the step program's (async) enqueue: its
+                                  lanes, tokens and live context tokens
         gimbal/readback           argmax read back: the step's sync point
         gimbal/stats_readback     MoE statistics read back
         gimbal/commit             per-lane append / finish / free

@@ -76,16 +76,30 @@ def _read_host(path):
 def served(tiny_model, tmp_path_factory):
     cfg, params = tiny_model
     runner = PagedModelRunner(cfg, params, ECFG, n_sources=2)
-    received = []                 # active lanes of every decode call
-    decode = runner.decode
+    # (kind, lanes of a decode call, live context tokens) of every call
+    received = []
+    decode, prefill, mixed = (runner.decode, runner.prefill_chunk,
+                              runner.mixed_step)
 
     def recording_decode(tokens, pages, lengths, block_tables, active,
                          placement, source_ids):
-        received.append(int(np.asarray(active).sum()))
+        act = np.asarray(active)
+        received.append(("decode", int(act.sum()),
+                         int((np.asarray(lengths)[act] + 1).sum())))
         return decode(tokens, pages, lengths, block_tables, active,
                       placement, source_ids)
 
+    def recording(kind, call):
+        def run(batch, *args):
+            received.append((kind, None, int(
+                np.asarray(batch["chunk_starts"]).sum()
+                + np.asarray(batch["chunk_lens"]).sum())))
+            return call(batch, *args)
+        return run
+
     runner.decode = recording_decode
+    runner.prefill_chunk = recording("prefill", prefill)
+    runner.mixed_step = recording("mixed", mixed)
     engines = [PagedRealEngine(0, cfg, params, ECFG, runner=runner,
                                n_sources=2),
                PagedRealEngine(1, cfg, params,
@@ -132,7 +146,8 @@ def test_span_arguments_count_the_work(served):
     reqs, res, got, _, received = served
     by = lambda n: [s for s in got if s.name == n]
     lanes = [s.args["lanes"] for s in by(spans.LAUNCH + "decode")]
-    assert lanes == received and len(lanes) > 0
+    assert lanes == [n for k, n, _ in received if k == "decode"]
+    assert len(lanes) > 0
     assert sum(s.args["n"] for s in by(spans.DISPATCH)) == len(reqs)
     assert sum(s.args["migrated"] for s in by(spans.REBALANCE)) \
         == res.signals["migrations"]
@@ -142,6 +157,16 @@ def test_span_arguments_count_the_work(served):
     for kind in spans.LAUNCH_KINDS:
         assert all(s.args["lanes"] >= 1 and s.args["tokens"] >= 1
                    for s in by(spans.LAUNCH + kind))
+
+
+def test_launch_spans_carry_the_live_context(served):
+    """``ctx``: the live context tokens the call attends over, each lane's
+    written tokens plus this call's."""
+    got, received = served[2], served[4]
+    for kind in spans.LAUNCH_KINDS:
+        ctx = [s.args["ctx"] for s in got if s.name == spans.LAUNCH + kind]
+        assert ctx == [c for k, _, c in received if k == kind]
+        assert len(ctx) > 0 and min(ctx) >= 1
 
 
 def test_step_programs_are_named_in_the_trace(served):

@@ -4,8 +4,9 @@ Interpret mode (every other kernel test) checks the math but none of the
 TPU compiler's rules: block tiling, SMEM/VMEM limits, what Mosaic can
 lower. Here each kernel is compiled at the Qwen3-30B-A3B widths the
 one-chip configuration serves (d_model 2048, d_expert 768, 128 experts,
-top-8, 32/4 heads of 128), for one chip of a described ``v5e:2x2``
-topology — no chip is needed, and nothing runs.
+top-8, 32/4 heads of 128), and the latent decode kernel at Kanana-2's
+(32 heads over rows of 512 + 64), for one chip of a described
+``v5e:2x2`` topology — no chip is needed, and nothing runs.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and every xdist worker
@@ -14,20 +15,29 @@ imports this file. The kernel modules are called with
 from the CPU backend).
 """
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.mla_decode import mla_decode_pallas
 from repro.kernels.moe_dispatch import padded_rows
 from repro.kernels.moe_gmm import moe_gmm_ragged
 from repro.kernels.paged_decode import (paged_decode_pallas,
                                         pages_per_block)
 from repro.kernels.source_expert_count import source_expert_count
 
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "bench"))
+from harness import trace  # noqa: E402
+
 D, F, E, K = 2048, 768, 128, 8          # d_model, d_expert, experts, top-k
 HQ, HKV, HD = 32, 4, 128                # query heads, KV heads, head_dim
+# Kanana-2-30B-A3B's latent rows: 512 latent + 64 roped key, padded to 640
+RANK, LATENT_PAD = 512, 640
 
 
 @pytest.fixture(scope="module")
@@ -86,6 +96,38 @@ def test_paged_decode_compiles(one_chip, B, NB, page_size, kv_dtype):
     ppb = pages_per_block(page_size, NB)
     nb = -(-NB // ppb) * ppb
     assert f"operand_layout_constraints={{s32[{B},{nb}]" in text
+
+
+@pytest.mark.parametrize("B,NB", [
+    # the long-document cell's decode: 16 lanes, a 1040-slot table of
+    # 16-token pages (prompts to 16384 plus 256 answer tokens)
+    pytest.param(16, 1040, id="kanana-16x1040"),
+    pytest.param(8, 72, id="8x72"),
+])
+def test_mla_decode_compiles(one_chip, B, NB):
+    specs = [_spec((B, HQ, LATENT_PAD), jnp.bfloat16, one_chip),
+             _spec((10241, 16, LATENT_PAD), jnp.bfloat16, one_chip),
+             _spec((B, NB), jnp.int32, one_chip),
+             _spec((B,), jnp.int32, one_chip)]
+    text = _compiled_text(
+        lambda q, p, bt, ctx: mla_decode_pallas(
+            q, p, bt, ctx, rank=RANK, scale=(128 + 64) ** -0.5,
+            interpret=False), *specs)
+    line = next(l for l in text.splitlines()
+                if re.match(r"\s*(ROOT )?%mla_decode", l))
+    # the first operand is ctx_lens, a vector: the trace reader's GQA
+    # pattern, a (lanes, slots) table in first place, does not match
+    assert f"operand_layout_constraints={{s32[{B}]{{0}}, " in line
+    # the operation as a profiler trace names it: the result, then each
+    # operand's shape before its name
+    result = line.strip().removeprefix("ROOT ").split(" custom-call(")[0]
+    shapes = re.findall(r"\w+\[[\d,]*\]\{[\d,]*\}", line.split(
+        "operand_layout_constraints=")[1].split(", frontend_attributes")[0])
+    assert len(shapes) == 5
+    operands = ", ".join(f"{sh} %arg{i}" for i, sh in enumerate(shapes))
+    op = (f'{result} custom-call({operands}), '
+          f'custom_call_target="tpu_custom_call"')
+    assert trace.kernel_of(op) == "mla_decode"
 
 
 @pytest.mark.parametrize("row_block", [8, 128])
