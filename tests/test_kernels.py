@@ -9,7 +9,8 @@ from repro.kernels.flash_decode import flash_decode
 from repro.kernels.moe_dispatch import (gmm_blocked_xla, padded_rows,
                                         pick_row_block, ragged_combine,
                                         ragged_dispatch)
-from repro.kernels.moe_gmm import moe_gmm, moe_gmm_ragged
+from repro.kernels.moe_gmm import (SLAB_BYTES, moe_gmm, moe_gmm_ragged,
+                                   weight_block_index, weight_slab)
 from repro.kernels.source_expert_count import source_expert_count
 
 RNG = np.random.default_rng(42)
@@ -67,13 +68,26 @@ def _skewed_ids(T, K, E, alpha, rng):
     return np.argpartition(-g, K, axis=1)[:, :K].astype(np.int32)
 
 
-@pytest.mark.parametrize("T,K,E,D,F,alpha", [
+_RAGGED_CASES = [
     (64, 1, 8, 64, 128, 0.0),      # tiny, uniform
     (200, 4, 16, 96, 160, 1.0),    # skewed, odd dims
     (256, 8, 64, 128, 96, 1.4),    # heavy skew: many empty experts
     (33, 2, 128, 72, 64, 2.0),     # E >> T*K: most groups empty
-])
-def test_moe_gmm_ragged_sweep(T, K, E, D, F, alpha):
+    (16, 2, 8, 2048, 768, 1.0),    # published widths (d_model, d_expert)
+]
+
+
+def _case_id(case):
+    return "-".join(str(v) for v in case)
+
+
+# each case under both weight tilings: the expert's whole (D, F) slab per
+# grid step, and a budget too small for any slab, which falls to slabs of
+# 128 columns with F padded up to a multiple of 128
+@pytest.mark.parametrize("T,K,E,D,F,alpha,split", [
+    pytest.param(*case, split, id=_case_id(case) + ("-split" if split else ""))
+    for case in _RAGGED_CASES for split in (False, True)])
+def test_moe_gmm_ragged_sweep(T, K, E, D, F, alpha, split):
     rng = np.random.default_rng(7)
     x2d = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
     phys = jnp.asarray(_skewed_ids(T, K, E, alpha, rng))
@@ -87,9 +101,11 @@ def test_moe_gmm_ragged_sweep(T, K, E, D, F, alpha):
         np.bincount(np.asarray(phys).ravel(), minlength=E))
 
     w = jnp.asarray(rng.normal(size=(E, D, F)), jnp.float32)
+    budget = 1 if split else D * F * 4
+    assert weight_slab(D, F, 4, budget) == (128 if split else F)
     y = moe_gmm_ragged(disp.xs, w, disp.tile_expert, disp.group_sizes,
-                       disp.padded_offsets, n_block=nb, f_block=64,
-                       d_block=64, interpret=True)
+                       disp.padded_offsets, n_block=nb, slab_bytes=budget,
+                       interpret=True)
     y_ref = ref.moe_gmm_ragged_ref(disp.xs, w, disp.group_sizes,
                                    disp.padded_offsets)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
@@ -107,6 +123,46 @@ def test_moe_gmm_ragged_sweep(T, K, E, D, F, alpha):
     expect = np.einsum("tk,tkf->tf", gn,
                        np.einsum("td,tkdf->tkf", xn, wn[pn]))
     np.testing.assert_allclose(out, expect, rtol=1e-4, atol=1e-2)
+
+
+@pytest.mark.parametrize("d_in,d_out,whole", [
+    (2048, 768, True),      # Qwen3 / Kanana gate and up
+    (768, 2048, True),      # their down projection
+    (5120, 8192, False),    # Llama 4 Maverick's experts split
+])
+def test_weight_slab_follows_the_shape(d_in, d_out, whole):
+    fb = weight_slab(d_in, d_out, 2)            # bf16
+    assert (fb == d_out) == whole
+    assert fb % 128 == 0 and d_out % fb == 0
+    assert d_in * fb * 2 <= SLAB_BYTES
+    # the widest such slab: twice as wide would not fit or not divide
+    if not whole:
+        assert d_out % (2 * fb) != 0 or d_in * 2 * fb * 2 > SLAB_BYTES
+
+
+@pytest.mark.parametrize("E,alpha,n_slabs", [(128, 1.2, 1), (64, 2.0, 3)])
+def test_weight_slab_fetched_once_per_touched_expert(E, alpha, n_slabs):
+    """The weight block index that the kernel's index map gives changes
+    only where the row tiles pass to another expert: over the grid, at
+    most (touched experts + 1) fetches per slab column, the one extra for
+    the dead tail tiles that map to expert E-1."""
+    rng = np.random.default_rng(3)
+    T, K = 512, 6
+    phys = jnp.asarray(_skewed_ids(T, K, E, alpha, rng))
+    nb = pick_row_block(T * K, E)
+    disp = ragged_dispatch(jnp.zeros((T, 8), jnp.float32), phys, E,
+                           row_block=nb)
+    te, sz, po = (np.asarray(a) for a in (
+        disp.tile_expert, disp.group_sizes, disp.padded_offsets))
+    touched = int((sz > 0).sum())
+    fetches, last = 0, None
+    for f in range(n_slabs):                    # the kernel's grid order
+        for n in range(len(te)):
+            block = weight_block_index(f, n, te, sz, po)
+            fetches += block != last
+            last = block
+    assert n_slabs * touched <= fetches <= n_slabs * (touched + 1)
+    assert len(te) > touched + 1                # tiles share a fetch
 
 
 def test_ragged_dispatch_dest_is_injective():
